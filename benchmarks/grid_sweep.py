@@ -123,29 +123,25 @@ def _event_reference(n_requests: int, n_arms: int = 2,
     return best
 
 
+def grid_sizes(quick: bool = False, *, smoke: bool = False, seed: int = 0):
+    """(fracs, sigmas, profiles, gates, n_steps, seeds) of one grid size."""
+    if smoke:
+        return (np.linspace(0.2, 0.8, 4), np.linspace(0.08, 0.2, 3),
+                _profiles()[:1], ("fixed",), 200, range(seed, seed + 4))
+    if quick:
+        return (np.linspace(0.1, 0.9, 8), np.linspace(0.05, 0.25, 8),
+                _profiles()[:2], ("fixed", "adaptive"), 300,
+                range(seed, seed + 4))
+    return (np.linspace(0.06, 0.94, 23), np.linspace(0.04, 0.26, 15),
+            _profiles(), ("fixed",), 400, range(seed, seed + 4))
+
+
 def grid_sweep(quick: bool = False, *, smoke: bool = False, seed: int = 0,
                report_timing: bool = True):
     """Returns (rows, headline, perf). ``perf`` carries the machine-readable
     numbers benchmarks/run.py persists to BENCH_substrate.json."""
-    if smoke:
-        fracs = np.linspace(0.2, 0.8, 4)
-        sigmas = np.linspace(0.08, 0.2, 3)
-        profiles = _profiles()[:1]
-        gates = ("fixed",)
-        n_steps, seeds = 200, range(seed, seed + 4)
-    elif quick:
-        fracs = np.linspace(0.1, 0.9, 8)
-        sigmas = np.linspace(0.05, 0.25, 8)
-        profiles = _profiles()[:2]
-        gates = ("fixed", "adaptive")
-        n_steps, seeds = 300, range(seed, seed + 4)
-    else:
-        fracs = np.linspace(0.06, 0.94, 23)
-        sigmas = np.linspace(0.04, 0.26, 15)
-        profiles = _profiles()
-        gates = ("fixed",)
-        n_steps, seeds = 400, range(seed, seed + 4)
-
+    fracs, sigmas, profiles, gates, n_steps, seeds = grid_sizes(
+        quick, smoke=smoke, seed=seed)
     arms, meta = build_grid(fracs, sigmas, profiles, gates)
     n_arms = len(meta)
     t0 = time.perf_counter()

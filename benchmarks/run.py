@@ -92,6 +92,9 @@ def main() -> None:
                     help="skip writing benchmarks/BENCH_substrate.json")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import (diurnal_sweep, fault_sweep, figs, fleet_sweep,
                    grid_sweep, kernels_micro, openloop_sweep,
                    pipeline_sweep, roofline_table, workflow_sweep)

@@ -30,8 +30,9 @@ cannot see — the ones that live in *state*, not syntax:
   whose instance slot is still legitimately held until their scheduled
   completion/crash event fires.
 * **finite outputs** — vectorized-sim summaries must be NaN/inf-free
-  (:func:`check_finite`), and the vectorized open-loop summary must
-  conserve requests per arm (:func:`check_open_summary`).
+  (:func:`check_finite`), and the vectorized closed- and open-loop
+  summaries must conserve requests per lane (:func:`check_closed_summary`,
+  :func:`check_open_summary`).
 
 Wrapping is per-instance (bound-method replacement on the engine/pool
 being sanitized), never global monkeypatching — two engines in one
@@ -424,6 +425,27 @@ def check_finite(summary: dict, *, where: str = "") -> None:
                   key=key, n_bad=n_bad, shape=arr.shape)
 
 
+def check_closed_summary(summary: dict, *, where: str = "") -> None:
+    """Vectorized closed-loop conservation per (arm, seed): every completed
+    request was billed once, as a cold pass or a warm reuse, and every
+    started instance either failed its probe or served."""
+    import numpy as np
+
+    check_finite(summary, where=where)
+    bill = np.asarray(summary["bill_n"])
+    term, passed, reused = bill[..., 0], bill[..., 1], bill[..., 2]
+    completed = np.asarray(summary["n_completed"])
+    started = np.asarray(summary["n_started"])
+    for what, lhs, rhs in (
+            ("passes + reuses != completed", passed + reused, completed),
+            ("terminations + passes != started", term + passed, started)):
+        if not np.array_equal(lhs, rhs):
+            bad = np.argwhere(lhs != rhs)
+            _fail(f"vectorized closed-loop conservation violated: {what}",
+                  where=where, first_bad_index=bad[:1].tolist(),
+                  lhs=float(lhs[tuple(bad[0])]), rhs=float(rhs[tuple(bad[0])]))
+
+
 def check_open_summary(summary: dict, n_steps: int, *,
                        where: str = "") -> None:
     """Vectorized open-loop conservation per (arm, stream): every offered
@@ -452,6 +474,7 @@ __all__ = [
     "attach_pool",
     "check_engine_conservation",
     "check_fault_ledger",
+    "check_closed_summary",
     "check_finite",
     "check_fleet_conservation",
     "check_open_loop",

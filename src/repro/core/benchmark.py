@@ -3,7 +3,7 @@
 The paper uses matrix multiplication as the CPU probe [10] and runs it
 during the function's network-bound *prepare* phase so it does not extend
 the critical path. Here the probe is the Pallas ``matmul_probe`` kernel
-(TPU-native MXU tiling, validated in interpret mode on CPU); the harness is
+(TPU-native MXU tiling; compiled on a TPU, interpreted elsewhere); the harness is
 pluggable so use-case-specific probes (memory streams, collective pings)
 can be swapped in.
 
@@ -54,7 +54,8 @@ class MatmulProbe:
     def work_ms_at_unit_speed(self) -> float:
         return self.flops / self.unit_speed_flops_per_ms
 
-    def _compute(self) -> jax.Array:
+    def compute(self) -> jax.Array:
+        """The probe's product chain (``repeats`` matmuls), not yet awaited."""
         from repro.kernels import ops
 
         a = jnp.full((self.n, self.n), 0.5, jnp.float32)
@@ -69,7 +70,7 @@ class MatmulProbe:
 
     def run(self) -> float:
         t0 = time.perf_counter()
-        jax.block_until_ready(self._compute())
+        jax.block_until_ready(self.compute())
         return (time.perf_counter() - t0) * 1e3
 
 

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
-
 _NEG_INF = -1e30
 
 
@@ -77,7 +75,7 @@ def decode_attention(
     *,
     sm_scale: float | None = None,
     block_k: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     batch, q_heads, one, d = q.shape
     if one != 1:
@@ -128,7 +126,7 @@ def decode_attention(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch * q_heads, 1, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

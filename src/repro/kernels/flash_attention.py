@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
-
 _NEG_INF = -1e30
 
 
@@ -86,7 +84,7 @@ def flash_attention(
     sm_scale: float | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     batch, q_heads, q_seq, d = q.shape
     _, kv_heads, kv_seq, _ = k.shape
@@ -137,7 +135,7 @@ def flash_attention(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
-
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
     """Grid = (M/bm, N/bn, K/bk); K is the innermost (sequential) axis so the
@@ -47,7 +45,7 @@ def matmul(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """C = A @ B with explicit MXU tiling. Shapes must divide the blocks
     (the ops wrapper pads otherwise)."""
@@ -74,7 +72,7 @@ def matmul(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,11 +1,14 @@
-"""Public jit'd wrappers around the Pallas kernels.
+"""Public wrappers around the Pallas kernels.
 
-Handles padding to block multiples, dtype management, and the
-interpret-mode switch: on CPU (this container) kernels execute via
-``interpret=True`` — the kernel body runs in Python on CPU, proving
-correctness; on TPU the same code lowers to Mosaic. ``use_pallas=False``
-falls back to the pure-jnp oracle (used inside pjit'd model code where a
-CPU-interpreted pallas_call cannot be SPMD-partitioned).
+Handles padding to block multiples, dtype management, and the interpret-mode
+decision: Pallas compiles these kernels for the TPU only, so a kernel whose
+operands live on a TPU runs compiled (Mosaic) and anywhere else its body
+runs in the Pallas interpreter. :func:`runs_interpreted` makes that choice
+per call from the operands, so importing this module touches no device.
+``use_pallas=False`` calls the pure-jnp oracle instead (used inside pjit'd
+model code where a CPU-interpreted pallas_call cannot be SPMD-partitioned).
+Shapes the attention kernels cannot tile raise; they are never handed to the
+oracle behind the caller's back.
 """
 from __future__ import annotations
 
@@ -13,15 +16,19 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from . import ref as _ref
 from .decode_attention import decode_attention as _decode_attention
 from .flash_attention import flash_attention as _flash_attention
 from .matmul_probe import matmul as _matmul
 
-_ON_TPU = any(d.platform == "tpu" for d in jax.devices()) if jax.process_count() >= 0 else False
-INTERPRET = not _ON_TPU
+
+def runs_interpreted(x: jax.Array) -> bool:
+    """True unless ``x`` is on a TPU. A traced operand has no placement yet;
+    it is judged by the backend the trace will compile for."""
+    if isinstance(x, jax.core.Tracer):
+        return jax.default_backend() != "tpu"
+    return any(d.platform != "tpu" for d in x.devices())
 
 
 def _pad_to(x: jax.Array, axis: int, multiple: int) -> tuple[jax.Array, int]:
@@ -54,7 +61,8 @@ def matmul(
     a, _ = _pad_to(a, 1, bk)
     b, _ = _pad_to(b, 0, bk)
     b, _ = _pad_to(b, 1, bn)
-    out = _matmul(a, b, block_m=bm, block_n=bn, block_k=bk, interpret=INTERPRET)
+    out = _matmul(a, b, block_m=bm, block_n=bn, block_k=bk,
+                  interpret=runs_interpreted(a))
     return out[:m, :n]
 
 
@@ -71,14 +79,10 @@ def flash_attention(
 ) -> jax.Array:
     if not use_pallas:
         return _ref.attention_ref(q, k, v, causal=causal, sm_scale=sm_scale)
-    q_seq, kv_seq = q.shape[2], k.shape[2]
-    bq, bk = min(block_q, q_seq), min(block_k, kv_seq)
-    if q_seq % bq or kv_seq % bk:
-        # padding attention needs mask plumbing; oracle handles ragged shapes
-        return _ref.attention_ref(q, k, v, causal=causal, sm_scale=sm_scale)
+    # ragged lengths raise in the kernel: padding needs length masking
     return _flash_attention(
-        q, k, v, causal=causal, sm_scale=sm_scale, block_q=bq, block_k=bk,
-        interpret=INTERPRET,
+        q, k, v, causal=causal, sm_scale=sm_scale, block_q=block_q,
+        block_k=block_k, interpret=runs_interpreted(q),
     )
 
 
@@ -94,12 +98,10 @@ def decode_attention(
 ) -> jax.Array:
     if not use_pallas:
         return _ref.decode_attention_ref(q, k_cache, v_cache, lengths, sm_scale=sm_scale)
-    s_len = k_cache.shape[2]
-    bk = min(block_k, s_len)
-    if s_len % bk:
-        return _ref.decode_attention_ref(q, k_cache, v_cache, lengths, sm_scale=sm_scale)
+    # a cache length that block_k does not divide raises in the kernel
     return _decode_attention(
-        q, k_cache, v_cache, lengths, sm_scale=sm_scale, block_k=bk, interpret=INTERPRET
+        q, k_cache, v_cache, lengths, sm_scale=sm_scale, block_k=block_k,
+        interpret=runs_interpreted(q),
     )
 
 

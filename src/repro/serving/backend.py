@@ -71,6 +71,7 @@ class ServeResult:
     replica_speed: float
     retries: int
     latency_ms: float = 0.0     # end-to-end simulated latency (queue + cold + body)
+    wall_ms: float = 0.0        # host wall clock to serve it, model compute included
 
 
 def _bucket(n: int, base: int = 1) -> int:
@@ -233,10 +234,7 @@ class ModelServingBackend:
 
         B = min(_bucket(max(1, load)), self.max_decode_batch)
         Tb = _bucket(T, base=self.decode_bucket)
-        # cache length is bucketed too, so decode_tokens executables are
-        # shared across prompt lengths that land in the same bucket (decode
-        # attention masks by `lengths`, so the padded tail is never read)
-        cache_len = _bucket(S + Tb, base=self.decode_bucket)
+        cache_len = self.cache_len(S, T)
         key = (cfg.family, B, S, Tb, cache_len)
         if key not in self._compiled_buckets:
             self._compiled_buckets.add(key)
@@ -254,6 +252,14 @@ class ModelServingBackend:
         toks, _ = model.decode_tokens(self.params, cache, tok, Tb)
         self.jit_stats["jit_calls"] += 1
         return np.asarray(toks[0, :T], np.int32)
+
+    def cache_len(self, prompt_len: int, max_new_tokens: int) -> int:
+        """KV-cache length the jitted path allocates for a request shape.
+        It is bucketed, so decode_tokens executables are shared across
+        prompt lengths that land in the same bucket (decode attention masks
+        by ``lengths``, so the padded tail is never read)."""
+        steps = _bucket(max_new_tokens, base=self.decode_bucket)
+        return _bucket(prompt_len + steps, base=self.decode_bucket)
 
     def time_model_ms(
         self, req: ServeRequest, *, mode: str, load: int = 1, repeats: int = 1,

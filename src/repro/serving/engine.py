@@ -25,6 +25,7 @@ a fleet.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import TYPE_CHECKING, Any, Optional
 
 import numpy as np
@@ -61,6 +62,8 @@ class MinosServingEngine(SubstrateEngine):
     ``serve`` keeps the historical synchronous semantics: requests are
     processed in order, each driven to completion on the shared simulated
     clock (so replica reuse compounds across the batch exactly as before).
+    ``model``/``params`` share another engine's weights and compiled
+    functions (built from ``seed`` when omitted).
     """
 
     def __init__(
@@ -85,6 +88,8 @@ class MinosServingEngine(SubstrateEngine):
         gate_load_aware: bool = False,
         decode_mode: str = "jit",
         controller=None,
+        model=None,
+        params=None,
     ) -> None:
         backend = ModelServingBackend(
             cfg,
@@ -101,6 +106,8 @@ class MinosServingEngine(SubstrateEngine):
             load_slowdown_alpha=load_slowdown_alpha,
             gate_load_aware=gate_load_aware,
             decode_mode=decode_mode,
+            model=model,
+            params=params,
         )
         knobs = (
             profile.knobs(max_pool=max_pool)
@@ -122,8 +129,10 @@ class MinosServingEngine(SubstrateEngine):
         results: list[ServeResult] = []
         for req in requests:
             done: list[RequestResult] = []
+            t0 = time.perf_counter()
             self.submit(req, done.append)
             self.loop.run_all()
+            wall_ms = (time.perf_counter() - t0) * 1e3
             assert done, "request did not complete"
             res = done[0]
             results.append(ServeResult(
@@ -133,6 +142,7 @@ class MinosServingEngine(SubstrateEngine):
                 replica_speed=res.instance_speed,
                 retries=res.retries,
                 latency_ms=res.latency_ms,
+                wall_ms=wall_ms,
             ))
         return results
 

@@ -1565,7 +1565,7 @@ def simulate_arms(
         # vmap axes lead, scan's step axis last → (arms, seeds, steps)
         requests = {k: np.asarray(v) for k, v in requests.items()}
     if _sanitizer.enabled():
-        _sanitizer.check_finite(summary, where="simulate_arms")
+        _sanitizer.check_closed_summary(summary, where="simulate_arms")
     return VecResult(summary=summary, requests=requests, n_arms=n_arms,
                      n_seeds=len(seeds), n_steps=int(n_steps))
 

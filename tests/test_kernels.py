@@ -100,3 +100,27 @@ def test_ops_fallback_matches_kernel():
     a = ops.flash_attention(q, k, v, use_pallas=True)
     b = ops.flash_attention(q, k, v, use_pallas=False)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("call", ["flash", "decode"])
+def test_ops_raise_on_untileable_shapes(call):
+    """A length the kernel cannot tile raises; it is never handed to the
+    jnp oracle behind the caller's back."""
+    if call == "flash":
+        q = _rand((1, 4, 100, 64), jnp.float32, 0)
+        kv = _rand((1, 2, 200, 64), jnp.float32, 1)
+        with pytest.raises(ValueError, match="must divide"):
+            ops.flash_attention(q, kv, kv, block_q=64, block_k=64)
+    else:
+        q = _rand((1, 4, 1, 64), jnp.float32, 0)
+        kv = _rand((1, 2, 300, 64), jnp.float32, 1)
+        with pytest.raises(ValueError, match="must divide"):
+            ops.decode_attention(q, kv, kv, jnp.array([10], jnp.int32))
+
+
+def test_interpret_mode_follows_operands():
+    """Off a TPU the kernels run interpreted, for concrete and traced
+    operands alike."""
+    x = jnp.ones((8, 8))
+    assert ops.runs_interpreted(x)
+    assert jax.jit(lambda y: jnp.float32(ops.runs_interpreted(y)))(x) == 1.0

@@ -155,8 +155,19 @@ def test_slot_loads_equal_replay_property(concurrency, alpha, order,
 
 LA_N_REQUESTS = 600
 LA_N_VUS = 8
-LA_EVENT_SEEDS = range(60)   # the event engine is cheap at this size; the
-LA_VEC_SEEDS = range(64)     # sample mass keeps the ±1pp bar meaningful
+# A seed sees ~17 probes here, so the pass rate varies by ~0.13 per seed;
+# 768 seeds per engine put the ±2pp bound at ~3 standard errors of the
+# engines' gap (measured gap over 3000 seeds each: -0.002).
+LA_EVENT_SEEDS = range(768)
+LA_VEC_SEEDS = range(768)
+
+
+def _pooled_pass_rate(res, arm: int) -> float:
+    """1 - terminations / probes over all seeds, as the event side pools
+    them. With ~17 probes a seed, the mean of per-seed rates sits ~2.5pp
+    above this: seeds that fail more probes also probe more."""
+    term = np.asarray(res.summary["n_terminated"][arm]).sum()
+    return 1.0 - float(term) / max(float(np.asarray(res.summary["n_probes"][arm]).sum()), 1.0)
 
 
 @pytest.fixture(scope="module")
@@ -192,7 +203,7 @@ def loaded_runs():
         vec[g] = {
             "analysis": np.asarray(res.requests["analysis_ms"][i])[comp],
             "latency": np.asarray(res.requests["latency_ms"][i])[comp],
-            "pass_rate": float(res.summary["pass_rate"][i].mean()),
+            "pass_rate": _pooled_pass_rate(res, i),
         }
     return event, vec
 

@@ -265,6 +265,24 @@ def test_vectorized_summary_guard(monkeypatch):
     assert np.isfinite(res.summary["mean_latency_ms"]).all()
 
 
+@pytest.mark.parametrize("lane,mutation,match", [
+    (0, {"bill_n": (0, 1, 0)}, "passes \\+ reuses != completed"),
+    (1, {"n_started": 3.0}, "terminations \\+ passes != started"),
+])
+def test_check_closed_summary_raises(lane, mutation, match):
+    """A double-billed request or an instance lost between start and probe
+    breaks the closed-loop scan's per-lane conservation."""
+    summary = {"bill_n": np.array([[[1.0, 3.0, 5.0], [0.0, 3.0, 5.0]]]),
+               "n_completed": np.full((1, 2), 8.0),
+               "n_started": np.array([[4.0, 3.0]])}
+    sanitizer.check_closed_summary(summary)  # consistent ledger passes
+    key, value = next(iter(mutation.items()))
+    bad = {k: v.copy() for k, v in summary.items()}
+    bad[key][0, lane] = bad[key][0, lane] + np.asarray(value)
+    with pytest.raises(SanitizerError, match=match):
+        sanitizer.check_closed_summary(bad)
+
+
 # ---------------------------------------------------------------------------
 # Fleet conservation ledger (repro.fleet; DESIGN.md §14)
 # ---------------------------------------------------------------------------

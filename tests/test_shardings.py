@@ -1,7 +1,7 @@
 """Sharding-rule logic (pure functions — no 512-device mesh needed)."""
 import jax
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.configs.registry import get_config
 from repro.distributed.sharding import use_mesh, shard, logical_to_spec
@@ -87,7 +87,7 @@ def test_shard_divisibility_guard_noop():
     """shard() drops axes the dim doesn't divide — a seq constraint on a
     1-token decode tensor must be harmless."""
     import jax.numpy as jnp
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,))
     with use_mesh(mesh, {"seq": "model", "batch": None}):
         x = jnp.ones((2, 1, 8))
         y = shard(x, "batch", "seq", None)  # seq dim of size 1
@@ -95,7 +95,7 @@ def test_shard_divisibility_guard_noop():
 
 
 def test_logical_to_spec_respects_rules():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,))
     with use_mesh(mesh, {"heads": "model", "batch": None}):
         assert logical_to_spec("batch", None, "heads", None) == \
             P(None, None, "model", None)

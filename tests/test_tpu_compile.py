@@ -1,0 +1,101 @@
+"""Compile the main path's device programs at real widths for a TPU v5e
+that is described, not attached: the three Pallas kernels compiled (not
+interpreted) and qwen3-0.6b's serving step at its published widths. What
+the chip's compiler would refuse (unaligned tiles, too much fast memory, a
+program that does not fit the device) fails here, at no chip time. Nothing
+runs, so this says nothing about results or times.
+
+The topology is described only inside the module fixture: one process at a
+time may load the TPU compiler's library, and it keeps it until it exits.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.registry import get_config
+from repro.kernels.decode_attention import decode_attention
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.matmul_probe import matmul
+from repro.models.model import build_model
+
+V5E_HBM_BYTES = 16 * 2**30
+QWEN = get_config("qwen3-0.6b")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import compilation_cache, topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler can be loaded here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_matmul_probe_compiles(one_chip):
+    a = _spec((512, 512), jnp.float32, one_chip)
+    text = matmul.lower(a, a, interpret=False).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("batch,seq", [(1, 512), (4, 128)])
+def test_flash_attention_compiles_at_qwen3_heads(one_chip, batch, seq):
+    hd = QWEN.head_dim
+    q = _spec((batch, QWEN.n_heads, seq, hd), jnp.bfloat16, one_chip)
+    kv = _spec((batch, QWEN.n_kv_heads, seq, hd), jnp.bfloat16, one_chip)
+    text = flash_attention.lower(q, kv, kv, causal=True,
+                                 interpret=False).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_decode_attention_compiles_at_qwen3_heads(one_chip):
+    batch, cache_len, hd = 4, 2048, QWEN.head_dim
+    q = _spec((batch, QWEN.n_heads, 1, hd), jnp.bfloat16, one_chip)
+    kv = _spec((batch, QWEN.n_kv_heads, cache_len, hd), jnp.bfloat16, one_chip)
+    lengths = _spec((batch,), jnp.int32, one_chip)
+    text = decode_attention.lower(q, kv, kv, lengths,
+                                  interpret=False).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("step", ["prefill_jit", "decode_tokens"])
+def test_qwen3_serving_step_fits_one_chip(one_chip, step):
+    """The shapes the serving path compiles for one 128-token prompt and 32
+    new tokens (cache bucket 256), at qwen3-0.6b's published widths."""
+    model = build_model(QWEN)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(lambda: model.init_cache(1, 256)))
+    if step == "prefill_jit":
+        tokens = _spec((1, 128), jnp.int32, one_chip)
+        compiled = model.prefill_jit.lower(params, {"tokens": tokens},
+                                           cache).compile()
+    else:
+        tok = _spec((1, 1), jnp.int32, one_chip)
+        compiled = model.decode_tokens.lower(params, cache, tok,
+                                             n_steps=32).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert weights > 1.1e9  # bf16 published widths, not the smoke variant
+    assert used < V5E_HBM_BYTES

@@ -49,8 +49,11 @@ SPEC = FunctionSpec(
 VM = VariationModel(sigma=0.15)
 THINK_MS = 500.0
 N_REQUESTS = 600
-EVENT_SEEDS = range(10)
-VEC_SEEDS = range(20)
+# The probe pass rate varies by ~0.026 per seed in both engines (measured
+# over 100 event / 300 scan seeds per cell); 40 seeds each put the ±2pp
+# bound at ~3.4 standard errors of the engines' gap.
+EVENT_SEEDS = range(40)
+VEC_SEEDS = range(40)
 GATES = ("off", "fixed", "adaptive")
 
 # analytic f=0.4 probe-duration quantile (probes are lognormal with
@@ -72,6 +75,14 @@ def _policy(gate: str):
     if gate == "fixed":
         return MinosPolicy(elysium_threshold=THRESHOLD, max_retries=5)
     return AdaptiveMinosPolicy(0.4, max_retries=5)
+
+
+def _pooled_pass_rate(res, arm: int) -> float:
+    """1 - terminations / probes over all seeds: the event engine's pooled
+    estimator. The mean of per-seed rates differs from it where seeds see
+    few probes, since seeds that fail more probes also probe more."""
+    term = np.asarray(res.summary["n_terminated"][arm]).sum()
+    return 1.0 - float(term) / max(float(np.asarray(res.summary["n_probes"][arm]).sum()), 1.0)
 
 
 @pytest.fixture(scope="module")
@@ -116,7 +127,7 @@ def runs():
             "analysis": res.requests["analysis_ms"][i].ravel(),
             "latency": res.requests["latency_ms"][i].ravel(),
             "billed": res.requests["billed_ms"][i].ravel(),
-            "pass_rate": float(res.summary["pass_rate"][i].mean()),
+            "pass_rate": _pooled_pass_rate(res, i),
             "cost_per_req": float(res.summary["cost"][i].mean()) / N_REQUESTS,
         }
     return event, vec
