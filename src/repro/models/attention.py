@@ -239,8 +239,9 @@ def decode_attention_step(
         return shard(y, "batch", None, None), k_cache, v_cache
     if update_cache:
         slot = lengths % S if window is not None else lengths
-        k_cache = _write_cache_row(k_cache, k_new, slot)
-        v_cache = _write_cache_row(v_cache, v_new, slot)
+        with jax.named_scope("kv_write"):
+            k_cache = _write_cache_row(k_cache, k_new, slot)
+            v_cache = _write_cache_row(v_cache, v_new, slot)
         valid = jnp.minimum(lengths + 1, S)
     else:
         valid = jnp.minimum(lengths, S)

@@ -74,7 +74,9 @@ def build_model(cfg: ArchConfig) -> Model:
         return mod.forward(cfg, params, batch["tokens"], remat=remat)
 
     def init_cache(batch_size: int, max_len: int):
-        return mod.init_cache(cfg, batch_size, max_len)
+        # eager, once per request: a host span on the profiler's clock
+        with jax.profiler.TraceAnnotation("minos.init_cache"):
+            return mod.init_cache(cfg, batch_size, max_len)
 
     def prefill(params, batch, cache):
         if fam == "encdec":
@@ -92,7 +94,8 @@ def build_model(cfg: ArchConfig) -> Model:
         def step(carry, _):
             tok, cache = carry
             logits, cache = mod.decode_step(cfg, params, cache, tok)
-            tok = greedy_token(logits)
+            with jax.named_scope("lm_head"):
+                tok = greedy_token(logits)
             return (tok, cache), tok
 
         (_, cache), toks = jax.lax.scan(

@@ -70,24 +70,31 @@ def _mlp_apply(cfg: ArchConfig, p_mlp, x):
     return swiglu(x, p_mlp["w_gate"], p_mlp["w_up"], p_mlp["w_down"]), 0.0
 
 
+# The named scopes "attn", "mlp" and "lm_head" (and "kv_write" inside
+# attention) label each compiled op's op_name; bench/scopes.py splits an
+# executable's device time by them.
 def _layer_prefill(cfg: ArchConfig, p, x, positions, window):
-    h, (k, v) = prefill_attention(
-        p["attn"], rms_norm(x, p["ln1"], cfg.norm_eps), positions,
-        rope_theta=cfg.rope_theta, eps=cfg.norm_eps, causal=True, window=window,
-    )
-    x = x + h
-    m, aux = _mlp_apply(cfg, p["mlp"], rms_norm(x, p["ln2"], cfg.norm_eps))
-    return x + m, (k, v), aux
+    with jax.named_scope("attn"):
+        h, (k, v) = prefill_attention(
+            p["attn"], rms_norm(x, p["ln1"], cfg.norm_eps), positions,
+            rope_theta=cfg.rope_theta, eps=cfg.norm_eps, causal=True, window=window,
+        )
+        x = x + h
+    with jax.named_scope("mlp"):
+        m, aux = _mlp_apply(cfg, p["mlp"], rms_norm(x, p["ln2"], cfg.norm_eps))
+        return x + m, (k, v), aux
 
 
 def _layer_decode(cfg: ArchConfig, p, x, k_cache, v_cache, lengths, window):
-    h, k_cache, v_cache = decode_attention_step(
-        p["attn"], rms_norm(x, p["ln1"], cfg.norm_eps), k_cache, v_cache, lengths,
-        rope_theta=cfg.rope_theta, eps=cfg.norm_eps, window=window,
-    )
-    x = x + h
-    m, _ = _mlp_apply(cfg, p["mlp"], rms_norm(x, p["ln2"], cfg.norm_eps))
-    return x + m, k_cache, v_cache
+    with jax.named_scope("attn"):
+        h, k_cache, v_cache = decode_attention_step(
+            p["attn"], rms_norm(x, p["ln1"], cfg.norm_eps), k_cache, v_cache, lengths,
+            rope_theta=cfg.rope_theta, eps=cfg.norm_eps, window=window,
+        )
+        x = x + h
+    with jax.named_scope("mlp"):
+        m, _ = _mlp_apply(cfg, p["mlp"], rms_norm(x, p["ln2"], cfg.norm_eps))
+        return x + m, k_cache, v_cache
 
 
 # ---------------------------------------------------------------------------
@@ -154,10 +161,11 @@ def prefill(cfg: ArchConfig, params, tokens: jax.Array, cache):
         return y, (k, v)
 
     x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = unembed(
-        x[:, -1:, :], params["unembed"] if "unembed" in params else params["embed"].T
-    )
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = unembed(
+            x[:, -1:, :], params["unembed"] if "unembed" in params else params["embed"].T
+        )
     S_c = cache["k"].shape[3]
     if window is not None and S > S_c:
         # keep the last `window` positions; ring alignment: slot = pos % window
@@ -188,7 +196,8 @@ def decode_step(cfg: ArchConfig, params, cache, tokens: jax.Array):
         return y, (kc, vc)
 
     x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = unembed(x, params["unembed"] if "unembed" in params else params["embed"].T)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = unembed(x, params["unembed"] if "unembed" in params else params["embed"].T)
     new_cache = {"k": ks, "v": vs, "lengths": lengths + 1}
     return logits, new_cache

@@ -1,0 +1,81 @@
+"""The named scopes of the serving executables, which the benchmark's trace
+reduction reads from each device op's HLO ``op_name`` to split device time
+into attention, KV-cache write, MLP and LM head (``bench/trace.py``).
+
+At smoke size on the CPU, for two dense families and one MoE. The compiled
+CPU text drops the metadata of some batched dots (a backend rewrite makes
+new instructions), so every matmul is checked in the lowered HLO, and the
+ones the compiled text still names are checked there too.
+"""
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.configs.registry import get_smoke_config
+from repro.models.model import build_model
+
+ARCHS = ["qwen3-0.6b", "phi3-mini-3.8b", "granite-moe-1b-a400m"]
+LAYERS = ("attn", "mlp", "lm_head")
+MATMUL = re.compile(r"= \S+ (?:dot|convolution)\(")
+OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+@pytest.fixture(scope="module", params=ARCHS)
+def lowered(request):
+    """(arch, {"prefill": Lowered, "decode": Lowered}) at the shapes the
+    serving path drives: an 8-token prompt, a 32-slot cache, 8 steps."""
+    model = build_model(get_smoke_config(request.param))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: model.init_cache(1, 32))
+    tok = jax.ShapeDtypeStruct((1, 1), jnp.int32)
+    prompt = {"tokens": jax.ShapeDtypeStruct((1, 8), jnp.int32)}
+    return request.param, {
+        "prefill": model.prefill_jit.lower(params, prompt, cache),
+        "decode": model.decode_tokens.lower(params, cache, tok, n_steps=8),
+    }
+
+
+def _op_names(text: str, pattern: re.Pattern) -> list[str | None]:
+    out = []
+    for line in text.splitlines():
+        if pattern.search(line):
+            m = OP_NAME.search(line)
+            out.append(m.group(1) if m else None)
+    return out
+
+
+def _layers(op_name: str) -> list[str]:
+    return [p for p in op_name.split("/") if p in LAYERS]
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_every_matmul_carries_one_layer_scope(lowered, step):
+    arch, low = lowered
+    names = _op_names(low[step].as_text(dialect="hlo", debug_info=True), MATMUL)
+    assert names, f"{arch} {step}: no matmul found"
+    for name in names:
+        assert name is not None and len(_layers(name)) == 1, (arch, step, name)
+    compiled = _op_names(low[step].compile().as_text(), MATMUL)
+    for name in filter(None, compiled):
+        assert len(_layers(name)) == 1, (arch, step, name)
+    assert any(compiled), f"{arch} {step}: the compiled text names no matmul"
+
+
+def test_decode_cache_write_carries_kv_write(lowered):
+    arch, low = lowered
+    text = low["decode"].compile().as_text()
+    writes = [n for n in _op_names(text, re.compile(r"dynamic-update-slice")) if n]
+    kv = [n for n in writes if "kv_write" in n.split("/")]
+    assert kv, f"{arch}: no cache write under kv_write in {writes}"
+    for name in kv:  # nested inside the attention layer
+        parts = name.split("/")
+        assert _layers(name) == ["attn"] and parts.index("attn") < parts.index("kv_write")
+
+
+def test_executables_keep_their_names(lowered):
+    arch, low = lowered
+    for step, module in (("prefill", "jit_prefill"), ("decode", "jit_decode_tokens")):
+        text = low[step].compile().as_text()
+        assert re.match(rf"HloModule {module}\b", text), (arch, text[:80])
