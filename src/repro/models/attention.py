@@ -33,8 +33,32 @@ DECODE_ATTN_MODE = "local"
 CACHE_UPDATE_MODE = "scatter"
 
 
-def _write_cache_row(cache: jax.Array, new: jax.Array, slot: jax.Array) -> jax.Array:
-    """cache: (B, K, S, hd); new: (B, K, 1, hd); slot: (B,) int32."""
+def _layer_of(cache: jax.Array, layer: Optional[jax.Array]) -> jax.Array:
+    """Layer ``layer`` of a stacked (L, B, K, S, hd) cache; ``cache`` itself
+    where ``layer`` is None."""
+    if layer is None:
+        return cache
+    return jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
+
+
+def _write_cache_row(cache: jax.Array, new: jax.Array, slot: jax.Array,
+                     layer: Optional[jax.Array] = None) -> jax.Array:
+    """cache: (B, K, S, hd), or with ``layer`` the stacked (L, B, K, S, hd)
+    whose layer ``layer`` is written; new: (B, K, 1, hd); slot: (B,) int32."""
+    if layer is not None:
+        if CACHE_UPDATE_MODE == "onehot":
+            return jax.lax.dynamic_update_index_in_dim(
+                cache, _write_cache_row(_layer_of(cache, layer), new, slot), layer, 0)
+        rows = new[:, :, 0].astype(cache.dtype)  # (B, K, hd), written in place
+        if cache.shape[1] == 1:
+            # one sequence: a dynamic_update_slice, which on TPU leaves the
+            # cache in the layout the attention reads without staging it
+            return jax.lax.dynamic_update_slice(
+                cache, rows[None, :, :, None], (layer, 0, 0, slot[0], 0))
+        # a batch: one scatter, which partitions where the batch is sharded;
+        # out-of-range slots clamp, as dynamic_update_slice's do
+        return cache.at[layer, jnp.arange(cache.shape[1]), :, slot, :].set(
+            rows, mode="clip", indices_are_sorted=True, unique_indices=True)
     if CACHE_UPDATE_MODE == "onehot":
         oh = jax.nn.one_hot(slot, cache.shape[2], dtype=cache.dtype)  # (B, S)
         return cache * (1.0 - oh[:, None, :, None]) + new * oh[:, None, :, None]
@@ -195,7 +219,7 @@ def _sharded_flash_decode(
 def decode_attention_step(
     p: AttnParams,
     x: jax.Array,                 # (B, 1, d) current token activations
-    k_cache: jax.Array,           # (B, K, S, hd)
+    k_cache: jax.Array,           # (B, K, S, hd), or (L, B, K, S, hd) with layer
     v_cache: jax.Array,
     lengths: jax.Array,           # (B,) current valid length (position of new tok)
     *,
@@ -204,6 +228,7 @@ def decode_attention_step(
     window: Optional[int] = None,
     use_rope: bool = True,
     update_cache: bool = True,
+    layer: Optional[jax.Array] = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One decode step. Returns (out (B,1,d), new_k_cache, new_v_cache).
 
@@ -211,9 +236,14 @@ def decode_attention_step(
     written at position ``lengths % window`` (ring buffer); attention masks
     to the min(lengths, window) most recent entries. RoPE uses absolute
     positions so rotations stay consistent in the ring.
+
+    With ``layer`` (an int32 scalar), the caches are the whole stack of
+    layers: the new row is written into layer ``layer`` in place, that
+    layer is attended over, and the stack is returned, so a layer scan can
+    carry it instead of slicing and re-stacking it every step.
     """
     B, _, d = x.shape
-    S = k_cache.shape[2]
+    S = k_cache.shape[-2]
     positions = lengths[:, None]  # (B, 1) absolute position of the new token
     q, k_new, v_new = _project_qkv(p, x, positions, rope_theta, eps, use_rope)
     qh = q.transpose(0, 2, 1, 3)              # (B, H, 1, hd)
@@ -230,22 +260,28 @@ def decode_attention_step(
 
         slot = lengths % S if window is not None else lengths
         valid = jnp.minimum(lengths + 1, S)
-        out, k_cache, v_cache = _sharded_flash_decode(
-            qh, k_cache, v_cache, k_new, v_new, slot, valid,
-            sm_scale=1.0 / _math.sqrt(qh.shape[-1]),
+        out, kc, vc = _sharded_flash_decode(
+            qh, _layer_of(k_cache, layer), _layer_of(v_cache, layer),
+            k_new, v_new, slot, valid, sm_scale=1.0 / _math.sqrt(qh.shape[-1]),
         )
+        if layer is None:
+            k_cache, v_cache = kc, vc
+        else:
+            k_cache = jax.lax.dynamic_update_index_in_dim(k_cache, kc, layer, 0)
+            v_cache = jax.lax.dynamic_update_index_in_dim(v_cache, vc, layer, 0)
         out = out.transpose(0, 2, 1, 3)
         y = jnp.einsum("bshk,hkd->bsd", out, p.wo)
         return shard(y, "batch", None, None), k_cache, v_cache
     if update_cache:
         slot = lengths % S if window is not None else lengths
         with jax.named_scope("kv_write"):
-            k_cache = _write_cache_row(k_cache, k_new, slot)
-            v_cache = _write_cache_row(v_cache, v_new, slot)
+            k_cache = _write_cache_row(k_cache, k_new, slot, layer)
+            v_cache = _write_cache_row(v_cache, v_new, slot, layer)
         valid = jnp.minimum(lengths + 1, S)
     else:
         valid = jnp.minimum(lengths, S)
-    out = kref.decode_attention_ref(qh, k_cache, v_cache, valid)
+    out = kref.decode_attention_ref(
+        qh, _layer_of(k_cache, layer), _layer_of(v_cache, layer), valid)
     out = out.transpose(0, 2, 1, 3)
     y = jnp.einsum("bshk,hkd->bsd", out, p.wo)
     return shard(y, "batch", None, None), k_cache, v_cache

@@ -85,11 +85,13 @@ def _layer_prefill(cfg: ArchConfig, p, x, positions, window):
         return x + m, (k, v), aux
 
 
-def _layer_decode(cfg: ArchConfig, p, x, k_cache, v_cache, lengths, window):
+def _layer_decode(cfg: ArchConfig, p, x, k_cache, v_cache, lengths, window, layer):
+    """k_cache/v_cache: the stacked (L, B, K, S, hd) caches; ``layer``'s row
+    is written in place and the stacks are returned."""
     with jax.named_scope("attn"):
         h, k_cache, v_cache = decode_attention_step(
             p["attn"], rms_norm(x, p["ln1"], cfg.norm_eps), k_cache, v_cache, lengths,
-            rope_theta=cfg.rope_theta, eps=cfg.norm_eps, window=window,
+            rope_theta=cfg.rope_theta, eps=cfg.norm_eps, window=window, layer=layer,
         )
         x = x + h
     with jax.named_scope("mlp"):
@@ -190,12 +192,17 @@ def decode_step(cfg: ArchConfig, params, cache, tokens: jax.Array):
     x = shard(x, "batch", "seq", None)
     lengths = cache["lengths"]
 
-    def body(x, layer):
-        p, kc, vc = layer
-        y, kc, vc = _layer_decode(cfg, p, x, kc, vc, lengths, window)
-        return y, (kc, vc)
+    # The stacked caches ride in the carry and each layer writes its row in
+    # place: as the scan's xs/ys they would be sliced and re-stacked whole.
+    def body(carry, layer):
+        x, ks, vs = carry
+        p, i = layer
+        y, ks, vs = _layer_decode(cfg, p, x, ks, vs, lengths, window, i)
+        return (y, ks, vs), None
 
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
+    layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+    (x, ks, vs), _ = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]), (params["layers"], layer_ids))
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = unembed(x, params["unembed"] if "unembed" in params else params["embed"].T)

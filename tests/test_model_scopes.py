@@ -79,3 +79,39 @@ def test_executables_keep_their_names(lowered):
     for step, module in (("prefill", "jit_prefill"), ("decode", "jit_decode_tokens")):
         text = low[step].compile().as_text()
         assert re.match(rf"HloModule {module}\b", text), (arch, text[:80])
+
+
+CACHE_SLOTS = 32  # the fixture's cache length
+DEFINES = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]")
+DUS = re.compile(r"dynamic-update-slice\(%([\w.\-]+), %([\w.\-]+)")
+
+
+def _dynamic_update_slices(text: str) -> list[tuple[tuple[int, ...], tuple[int, ...], str | None]]:
+    """(operand shape, update shape, op_name) of every dynamic-update-slice."""
+    shapes = {}
+    for line in text.splitlines():
+        m = DEFINES.match(line)
+        if m:
+            shapes[m.group(1)] = tuple(int(d) for d in m.group(2).split(",") if d)
+    out = []
+    for line in text.splitlines():
+        m = DUS.search(line)
+        if m:
+            name = OP_NAME.search(line)
+            out.append((shapes[m.group(1)], shapes[m.group(2)], name and name.group(1)))
+    return out
+
+
+def test_decode_writes_only_rows_into_the_cache(lowered):
+    """The layer scan carries the stacked cache and writes each new row in
+    place: no write copies a layer's whole cache (the length-S axis), and
+    every write under kv_write is one slot of the stacked buffer."""
+    arch, low = lowered
+    writes = _dynamic_update_slices(low["decode"].compile().as_text())
+    for operand, update, name in writes:
+        assert CACHE_SLOTS not in update, (arch, operand, update, name)
+    kv = [(o, u) for o, u, n in writes if n and "kv_write" in n.split("/")]
+    assert kv, f"{arch}: no cache write under kv_write"
+    for operand, update in kv:
+        assert len(operand) == 5 and operand[-2] == CACHE_SLOTS, (arch, operand)
+        assert update[-2] == 1 and update[:2] == (1, 1), (arch, operand, update)

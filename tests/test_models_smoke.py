@@ -2,6 +2,7 @@
 d_model<=512, <=4 experts), one forward + one train step on CPU, asserting
 output shapes and no NaNs — as required for deliverable (f)."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -9,6 +10,9 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import ARCH_IDS, get_config, get_smoke_config
+from repro.models import transformer
+from repro.models.attention import decode_attention_step
+from repro.models.layers import rms_norm, unembed
 from repro.models.model import build_model, greedy_token
 from repro.optim.adamw import AdamW
 
@@ -128,6 +132,57 @@ def test_sliding_window_decode_matches_windowed_forward():
     got, _ = model.decode_step(params, cache, tokens[:, -1:])
     err = np.max(np.abs(np.asarray(got[:, 0]) - want)) / (np.max(np.abs(want)) + 1e-9)
     assert err < 2e-3, err
+
+
+def _per_layer_decode_step(cfg, params, cache, tok):
+    """One greedy step as the layer scan ran before it carried the cache:
+    each layer's cache sliced out as the scan's xs, updated alone, and
+    re-stacked as its ys."""
+    eps = cfg.norm_eps
+    x = jnp.take(params["embed"], tok, axis=0)
+    lengths = cache["lengths"]
+
+    def body(x, layer):
+        p, kc, vc = layer
+        h, kc, vc = decode_attention_step(
+            p["attn"], rms_norm(x, p["ln1"], eps), kc, vc, lengths,
+            rope_theta=cfg.rope_theta, eps=eps, window=cfg.sliding_window)
+        x = x + h
+        m, _ = transformer._mlp_apply(cfg, p["mlp"], rms_norm(x, p["ln2"], eps))
+        return x + m, (kc, vc)
+
+    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
+    x = rms_norm(x, params["final_norm"], eps)
+    logits = unembed(x, params["unembed"] if "unembed" in params else params["embed"].T)
+    return greedy_token(logits), {"k": ks, "v": vs, "lengths": lengths + 1}
+
+
+@pytest.mark.parametrize("arch,window", [("llama3.2-1b", 16), ("granite-moe-1b-a400m", None)])
+def test_decode_tokens_matches_per_layer_cache_decode(arch, window):
+    """decode_tokens (stacked cache carried through the layer scan, rows
+    written in place) == a step-by-step loop over per-layer caches: the same
+    tokens and the same final cache. The two sequences sit at different
+    lengths, and with the window both cross the ring's wrap."""
+    cfg = dataclasses.replace(get_smoke_config(arch), sliding_window=window)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(5))
+    B, S, T = 2, 12, 12
+    tokens = jax.random.randint(jax.random.PRNGKey(6), (B, S), 0, cfg.vocab)
+    _, cache = model.prefill(params, {"tokens": tokens}, model.init_cache(B, 64))
+    cache["lengths"] = jnp.array([S, S - 5], jnp.int32)
+    if window is not None:
+        assert cache["k"].shape[3] == window < S + T - 5
+    tok = tokens[:, -1:]
+    got, got_cache = model.decode_tokens(params, cache, tok, T)
+
+    step = jax.jit(functools.partial(_per_layer_decode_step, cfg))
+    want, want_cache = [], cache
+    for _ in range(T):
+        tok, want_cache = step(params, want_cache, tok)
+        want.append(tok)
+    np.testing.assert_array_equal(np.asarray(got), np.concatenate(want, axis=1))
+    for key in ("k", "v", "lengths"):
+        np.testing.assert_array_equal(np.asarray(got_cache[key]), np.asarray(want_cache[key]))
 
 
 def test_moe_load_balance_loss_positive():

@@ -9,6 +9,7 @@ The topology is described only inside the module fixture: one process at a
 time may load the TPU compiler's library, and it keeps it until it exits.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -99,3 +100,53 @@ def test_qwen3_serving_step_fits_one_chip(one_chip, step):
     weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
     assert weights > 1.1e9  # bf16 published widths, not the smoke variant
     assert used < V5E_HBM_BYTES
+
+
+COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
+CALLED = re.compile(r"\b(?:calls|body|condition|to_apply)=%([\w.\-]+)")
+WHILE_BODY = re.compile(r"\bwhile\(.*\bbody=%([\w.\-]+)")
+
+
+def _computations(text: str) -> dict[str, list[str]]:
+    comps, name = {}, None
+    for line in text.splitlines():
+        m = COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif line == "}":
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def test_decode_loop_copies_no_whole_cache(one_chip):
+    """qwen3-0.6b's decode at 2,048 cache slots, as the chip's compiler
+    builds it: the layer scan carries the stacked cache, so no while body
+    (nor anything it calls) copies the whole stacked cache. The copies at
+    the executable's entry and exit run once per request."""
+    model = build_model(QWEN)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(lambda: model.init_cache(1, 2048)))
+    stacked = "bf16[%s]" % ",".join(map(str, cache["k"].shape))
+    assert stacked == "bf16[28,1,8,2048,128]"
+    tok = _spec((1, 1), jnp.int32, one_chip)
+    text = model.decode_tokens.lower(params, cache, tok, n_steps=16).compile().as_text()
+    comps = _computations(text)
+    todo = [m.group(1) for line in text.splitlines() if (m := WHILE_BODY.search(line))]
+    assert len(todo) >= 2, "the step loop and the layer loop"
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            todo += CALLED.findall(line)
+            assert not re.search(re.escape(stacked) + r"\{[^}]*\} copy(?:-start)?\(", line), (
+                name, line[:200])
