@@ -18,6 +18,27 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_z_weight: float = 1e-3
     load_balance_weight: float = 1e-2
+    # the experts this chip holds under expert parallelism: ids first_held
+    # .. first_held + n_held - 1 (n_held 0: all of them). The router keeps
+    # all n_experts outputs; the layer computes its held experts' share.
+    n_held: int = 0
+    first_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeConfig:
+    """RoPE of one kind of layer. ``yarn_factor`` 0 is the default RoPE;
+    otherwise YaRN (arXiv:2309.00071) as Hugging Face computes it."""
+    theta: float
+    yarn_factor: float = 0.0
+    original_max_positions: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,6 +77,11 @@ class ArchConfig:
     encoder_frames: int = 1500   # stub conv frontend output length
     # sliding-window attention (enables long_500k for dense archs)
     sliding_window: Optional[int] = None
+    # one period of the layer pattern, each "window" (windowed by
+    # sliding_window) or "full"; () gives every layer one kind
+    layer_types: tuple[str, ...] = ()
+    # RoPE by layer kind where it is not the default RoPE at rope_theta
+    rope_by_kind: tuple[tuple[str, RopeConfig], ...] = ()
     dtype: str = "bfloat16"
     # citation for the assigned config
     source: str = ""
@@ -63,6 +89,19 @@ class ArchConfig:
     def __post_init__(self):
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.n_layers % len(self.period):
+            raise ValueError(f"{self.n_layers} layers are not whole periods of {self.period}")
+
+    @property
+    def period(self) -> tuple[str, ...]:
+        """The kinds of one period of layers; the layer scan steps by periods."""
+        return self.layer_types or ("window" if self.sliding_window else "full",)
+
+    def window_of(self, kind: str) -> Optional[int]:
+        return self.sliding_window if kind == "window" else None
+
+    def rope_of(self, kind: str) -> RopeConfig:
+        return dict(self.rope_by_kind).get(kind, RopeConfig(self.rope_theta))
 
     @property
     def jax_dtype(self):
@@ -133,14 +172,15 @@ class ArchConfig:
 
 
 def smoke_variant(cfg: ArchConfig, **overrides) -> ArchConfig:
-    """Family-preserving reduced config: 2 layers, d_model<=512, <=4 experts."""
+    """Family-preserving reduced config: 2 layers (one period where the layer
+    pattern is longer), d_model<=512, <=4 experts."""
     d = min(cfg.d_model, 256)
     heads = min(cfg.n_heads, 4)
     kv = min(cfg.n_kv_heads, max(1, heads // 2))
     while heads % kv:
         kv -= 1
     changes = dict(
-        n_layers=2,
+        n_layers=max(2, len(cfg.layer_types)),
         d_model=d,
         n_heads=heads,
         n_kv_heads=kv,

@@ -16,6 +16,7 @@ _MODULES = {
     "chameleon-34b": "chameleon_34b",
     "granite-moe-1b-a400m": "granite_moe_1b_a400m",
     "mistral-large-123b": "mistral_large_123b",
+    "mellum2-12b-a2.5b": "mellum2_12b_a2_5b",
 }
 
 ARCH_IDS = tuple(_MODULES)

@@ -129,7 +129,9 @@ def param_spec(path: str, shape: tuple[int, ...], cfg: ArchConfig, mesh: Mesh) -
 def cache_spec(path: str, shape: tuple[int, ...], cfg: ArchConfig, mesh: Mesh,
                data_axes) -> P:
     """KV caches / recurrent state sharding for decode/prefill."""
-    name = path.split("/")[-1]
+    parts = path.split("/")
+    # a transformer keeps its "k" and "v" stacks by kind of layer
+    name = parts[-2] if len(parts) > 1 and parts[-1] in ("window", "full") else parts[-1]
     M = MODEL_AXIS
     msize = _mesh_size(mesh, M)
     nd = len(shape)

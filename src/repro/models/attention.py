@@ -5,6 +5,7 @@ are the TPU path and are validated separately in tests.
 """
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -94,7 +95,7 @@ def init_attention(key, d_model: int, n_heads: int, n_kv_heads: int, head_dim: i
 
 
 def _project_qkv(p: AttnParams, x: jax.Array, positions: jax.Array,
-                 rope_theta: float, eps: float, use_rope: bool = True):
+                 rope_theta: float, eps: float, use_rope: bool = True, yarn=None):
     """x: (B, S, d) -> q (B, S, H, hd), k/v (B, S, K, hd)."""
     q = jnp.einsum("bsd,dhk->bshk", x, p.wq)
     k = jnp.einsum("bsd,dhk->bshk", x, p.wk)
@@ -103,8 +104,8 @@ def _project_qkv(p: AttnParams, x: jax.Array, positions: jax.Array,
         q = rms_norm(q, p.q_norm, eps)
         k = rms_norm(k, p.k_norm, eps)
     if use_rope:
-        q = rope(q, positions, rope_theta)
-        k = rope(k, positions, rope_theta)
+        q = rope(q, positions, rope_theta, yarn)
+        k = rope(k, positions, rope_theta, yarn)
     q = shard(q, "batch", None, "heads", None)
     k = shard(k, "batch", None, "kv_heads", None)
     v = shard(v, "batch", None, "kv_heads", None)
@@ -122,10 +123,12 @@ def prefill_attention(
     window: Optional[int] = None,
     use_rope: bool = True,
     cross_kv: Optional[tuple[jax.Array, jax.Array]] = None,  # (B, S_kv, K, hd)
+    yarn=None,
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
-    """Returns (out (B,S,d), (k_cache, v_cache) in (B,K,S,hd) layout)."""
+    """Returns (out (B,S,d), (k_cache, v_cache) in (B,K,S,hd) layout).
+    ``yarn`` (a ``RopeConfig``) switches RoPE to YaRN (``layers.rope``)."""
     if cross_kv is None:
-        q, k, v = _project_qkv(p, x, positions, rope_theta, eps, use_rope)
+        q, k, v = _project_qkv(p, x, positions, rope_theta, eps, use_rope, yarn)
     else:
         q = jnp.einsum("bsd,dhk->bshk", x, p.wq)
         if p.q_norm is not None:
@@ -137,11 +140,49 @@ def prefill_attention(
     qh = q.transpose(0, 2, 1, 3)
     kh = k.transpose(0, 2, 1, 3)
     vh = v.transpose(0, 2, 1, 3)
-    out = kref.attention_ref(qh, kh, vh, causal=causal, window=window)
+    bq = _band_block(qh.shape[2], window) if causal and cross_kv is None else None
+    if bq:
+        out = _banded_attention(qh, kh, vh, window, bq)
+    else:
+        out = kref.attention_ref(qh, kh, vh, causal=causal, window=window)
     out = out.transpose(0, 2, 1, 3)  # (B, S, H, hd)
     y = jnp.einsum("bshk,hkd->bsd", out, p.wo)
     y = shard(y, "batch", "seq", None)
     return y, (kh, vh)
+
+
+def _band_block(S: int, window: Optional[int]) -> Optional[int]:
+    """Queries a block of the banded window attention holds (a quarter of the
+    window), or None where the band would not be narrower than the prompt."""
+    bq = window // 4 if window else 0
+    return bq if bq and S % bq == 0 and S > window + bq else None
+
+
+def _banded_attention(q: jax.Array, k: jax.Array, v: jax.Array, window: int,
+                      bq: int) -> jax.Array:
+    """Causal attention of each query over the last ``window`` keys,
+    ``kref.attention_ref``'s masked softmax computed by blocks of ``bq``
+    queries: a block's scores span only the window + bq keys that reach it,
+    not the whole prompt. q: (B, H, S, hd), k/v: (B, K, S, hd)."""
+    B, H, S, d = q.shape
+    K = k.shape[1]
+    nb, span = S // bq, window + bq
+
+    def bands(a):  # (B, K, S, d) -> (B, K, nb, span, d): block b's keys from b·bq − window
+        a = jnp.pad(a.astype(jnp.float32), ((0, 0), (0, 0), (window, 0), (0, 0)))
+        a = a.reshape(B, K, nb + window // bq, bq, d)
+        return jnp.concatenate([a[:, :, i:i + nb] for i in range(span // bq)], axis=3)
+
+    qg = q.astype(jnp.float32).reshape(B, K, H // K, nb, bq, d)
+    s = jnp.einsum("bkgnqd,bknsd->bkgnqs", qg, bands(k),
+                   preferred_element_type=jnp.float32) * (1.0 / math.sqrt(d))
+    i = jnp.arange(bq)[:, None]                                  # query in its block
+    j = jnp.arange(span)[None, :]                                # key in the block's span
+    first = window - jnp.arange(nb)[:, None, None] * bq          # span index of key 0
+    sees = (j > i) & (j <= i + window) & (j >= first)            # (nb, bq, span)
+    s = jnp.where(sees[None, None, None], s, -1e30)
+    out = jnp.einsum("bkgnqs,bknsd->bkgnqd", jax.nn.softmax(s, axis=-1), bands(v))
+    return out.reshape(B, H, S, d).astype(q.dtype)
 
 
 def _sharded_flash_decode(
@@ -229,6 +270,7 @@ def decode_attention_step(
     use_rope: bool = True,
     update_cache: bool = True,
     layer: Optional[jax.Array] = None,
+    yarn=None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One decode step. Returns (out (B,1,d), new_k_cache, new_v_cache).
 
@@ -245,7 +287,7 @@ def decode_attention_step(
     B, _, d = x.shape
     S = k_cache.shape[-2]
     positions = lengths[:, None]  # (B, 1) absolute position of the new token
-    q, k_new, v_new = _project_qkv(p, x, positions, rope_theta, eps, use_rope)
+    q, k_new, v_new = _project_qkv(p, x, positions, rope_theta, eps, use_rope, yarn)
     qh = q.transpose(0, 2, 1, 3)              # (B, H, 1, hd)
     k_new = k_new.transpose(0, 2, 1, 3)       # (B, K, 1, hd)
     v_new = v_new.transpose(0, 2, 1, 3)

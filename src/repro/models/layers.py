@@ -1,8 +1,11 @@
 """Shared neural building blocks (pure JAX, no framework deps)."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.distributed.sharding import shard
 
@@ -27,14 +30,40 @@ def layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array, eps: float = 1e
     return (x * weight.astype(jnp.float32) + bias.astype(jnp.float32)).astype(dt)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding. x: (..., seq, heads, head_dim); positions: (..., seq)."""
+def yarn_frequencies(hd: int, r) -> np.ndarray:
+    """YaRN's inverse frequencies (hd // 2,) for a ``RopeConfig`` ``r``, as
+    Hugging Face's ``_compute_yarn_parameters`` gives them: dimensions that
+    turn fewer than beta_slow times over the original context are
+    interpolated by yarn_factor, those that turn more than beta_fast times
+    are kept, and a linear ramp blends the ones between."""
+    def dim(rotations):  # the dimension that turns `rotations` times
+        return hd * math.log(r.original_max_positions / (2 * math.pi * rotations)) / (
+            2 * math.log(r.theta))
+
+    low = max(math.floor(dim(r.beta_fast)), 0)
+    high = min(math.ceil(dim(r.beta_slow)), hd - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(hd // 2) - low) / (high - low), 0.0, 1.0)
+    plain = 1.0 / r.theta ** (np.arange(0, hd, 2) / hd)
+    return (plain / r.yarn_factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float, yarn=None) -> jax.Array:
+    """Rotary embedding. x: (..., seq, heads, head_dim); positions: (..., seq).
+    With ``yarn`` (a ``RopeConfig``), YaRN's frequencies at its theta, and
+    cos and sin scaled by its attention_factor."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if yarn is None:
+        freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    else:
+        freqs = jnp.asarray(yarn_frequencies(hd, yarn))
     angles = positions.astype(jnp.float32)[..., None] * freqs  # (..., seq, half)
     cos = jnp.cos(angles)[..., None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1

@@ -2,7 +2,11 @@
 reduction reads from each device op's HLO ``op_name`` to split device time
 into attention, KV-cache write, MLP and LM head (``bench/trace.py``).
 
-At smoke size on the CPU, for two dense families and one MoE. The compiled
+Inside them, each layer's attention names its kind ("window" or "full")
+and a MoE names its routing, expert products and combine.
+
+At smoke size on the CPU, for two dense families and two MoE, one of them
+with window and full layers. The compiled
 CPU text drops the metadata of some batched dots (a backend rewrite makes
 new instructions), so every matmul is checked in the lowered HLO, and the
 ones the compiled text still names are checked there too.
@@ -16,8 +20,10 @@ import pytest
 from repro.configs.registry import get_smoke_config
 from repro.models.model import build_model
 
-ARCHS = ["qwen3-0.6b", "phi3-mini-3.8b", "granite-moe-1b-a400m"]
+ARCHS = ["qwen3-0.6b", "phi3-mini-3.8b", "granite-moe-1b-a400m", "mellum2-12b-a2.5b"]
 LAYERS = ("attn", "mlp", "lm_head")
+KINDS = ("window", "full")                              # inside "attn"
+MOE = ("moe_route", "moe_experts", "moe_combine")       # inside "mlp"
 MATMUL = re.compile(r"= \S+ (?:dot|convolution)\(")
 OP_NAME = re.compile(r'op_name="([^"]*)"')
 
@@ -61,6 +67,22 @@ def test_every_matmul_carries_one_layer_scope(lowered, step):
     for name in filter(None, compiled):
         assert len(_layers(name)) == 1, (arch, step, name)
     assert any(compiled), f"{arch} {step}: the compiled text names no matmul"
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_attention_kinds_and_moe_scopes(lowered, step):
+    """Every kind of layer in the period names its attention, inside "attn";
+    a MoE's three stages are named inside "mlp", and only a MoE's."""
+    arch, low = lowered
+    cfg = get_smoke_config(arch)
+    paths = [n.split("/") for n in
+             OP_NAME.findall(low[step].as_text(dialect="hlo", debug_info=True))]
+    for parent, inner in (("attn", KINDS), ("mlp", MOE)):
+        for p in paths:
+            for name in set(inner) & set(p):
+                assert parent in p and p.index(parent) < p.index(name), (arch, step, p)
+    assert {k for p in paths for k in KINDS if k in p} == set(cfg.period), arch
+    assert {m for p in paths for m in MOE if m in p} == (set(MOE) if cfg.moe else set()), arch
 
 
 def test_decode_cache_write_carries_kv_write(lowered):

@@ -33,7 +33,8 @@ def _batch(cfg, B=2, S=32):
 def test_smoke_config_is_reduced(arch):
     cfg = get_smoke_config(arch)
     full = get_config(arch)
-    assert cfg.n_layers == 2
+    assert cfg.n_layers == max(2, len(full.layer_types))  # one whole period
+    assert cfg.layer_types == full.layer_types
     assert cfg.d_model <= 512
     assert cfg.family == full.family
     if cfg.moe is not None:
@@ -127,7 +128,7 @@ def test_sliding_window_decode_matches_windowed_forward():
     full, _ = model.forward(params, {"tokens": tokens})
     want = np.asarray(full[:, -1])
     cache = model.init_cache(B, S)
-    assert cache["k"].shape[3] == 16  # ring of window size
+    assert cache["k"]["window"].shape[3] == 16  # ring of window size
     _, cache = model.prefill(params, {"tokens": tokens[:, :-1]}, cache)
     got, _ = model.decode_step(params, cache, tokens[:, -1:])
     err = np.max(np.abs(np.asarray(got[:, 0]) - want)) / (np.max(np.abs(want)) + 1e-9)
@@ -148,13 +149,14 @@ def _per_layer_decode_step(cfg, params, cache, tok):
             p["attn"], rms_norm(x, p["ln1"], eps), kc, vc, lengths,
             rope_theta=cfg.rope_theta, eps=eps, window=cfg.sliding_window)
         x = x + h
-        m, _ = transformer._mlp_apply(cfg, p["mlp"], rms_norm(x, p["ln2"], eps))
+        m, _ = transformer._mlp_serve(cfg, p["mlp"], rms_norm(x, p["ln2"], eps))
         return x + m, (kc, vc)
 
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
+    kind, = cfg.period
+    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], cache["k"][kind], cache["v"][kind]))
     x = rms_norm(x, params["final_norm"], eps)
     logits = unembed(x, params["unembed"] if "unembed" in params else params["embed"].T)
-    return greedy_token(logits), {"k": ks, "v": vs, "lengths": lengths + 1}
+    return greedy_token(logits), {"k": {kind: ks}, "v": {kind: vs}, "lengths": lengths + 1}
 
 
 @pytest.mark.parametrize("arch,window", [("llama3.2-1b", 16), ("granite-moe-1b-a400m", None)])
@@ -171,7 +173,7 @@ def test_decode_tokens_matches_per_layer_cache_decode(arch, window):
     _, cache = model.prefill(params, {"tokens": tokens}, model.init_cache(B, 64))
     cache["lengths"] = jnp.array([S, S - 5], jnp.int32)
     if window is not None:
-        assert cache["k"].shape[3] == window < S + T - 5
+        assert cache["k"]["window"].shape[3] == window < S + T - 5
     tok = tokens[:, -1:]
     got, got_cache = model.decode_tokens(params, cache, tok, T)
 
@@ -181,8 +183,8 @@ def test_decode_tokens_matches_per_layer_cache_decode(arch, window):
         tok, want_cache = step(params, want_cache, tok)
         want.append(tok)
     np.testing.assert_array_equal(np.asarray(got), np.concatenate(want, axis=1))
-    for key in ("k", "v", "lengths"):
-        np.testing.assert_array_equal(np.asarray(got_cache[key]), np.asarray(want_cache[key]))
+    jax.tree.map(lambda g, w: np.testing.assert_array_equal(np.asarray(g), np.asarray(w)),
+                 got_cache, want_cache)
 
 
 def test_moe_load_balance_loss_positive():

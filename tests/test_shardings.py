@@ -78,6 +78,17 @@ def test_cache_spec_kv_head_fallbacks():
     assert llama.n_kv_heads % 16 != 0 and llama.head_dim % 16 == 0
 
 
+@pytest.mark.parametrize("kind", ["window", "full"])
+def test_cache_spec_reads_a_stack_under_its_kind(kind):
+    """A transformer's cache keeps "k" and "v" by kind of layer: the stack
+    under "k/<kind>" is sharded as "k" is."""
+    cfg = get_config("mellum2-12b-a2.5b")
+    shape = (21, 128, 4, 1024, 128)
+    assert cache_spec(f"k/{kind}", shape, cfg, MESH, ("data",)) == \
+        cache_spec("k", shape, cfg, MESH, ("data",))
+    assert cache_spec(f"v/{kind}", shape, cfg, MESH, ("data",))[2] == "model"
+
+
 def test_batch_spec():
     assert batch_spec("tokens", (256, 4096), MESH, ("data",)) == \
         P(("data",), None)

@@ -8,6 +8,7 @@ runs, so this says nothing about results or times.
 The topology is described only inside the module fixture: one process at a
 time may load the TPU compiler's library, and it keeps it until it exits.
 """
+import dataclasses
 import os
 import re
 
@@ -121,22 +122,50 @@ def _computations(text: str) -> dict[str, list[str]]:
     return comps
 
 
-def test_decode_loop_copies_no_whole_cache(one_chip):
-    """qwen3-0.6b's decode at 2,048 cache slots, as the chip's compiler
-    builds it: the layer scan carries the stacked cache, so no while body
-    (nor anything it calls) copies the whole stacked cache. The copies at
-    the executable's entry and exit run once per request."""
-    model = build_model(QWEN)
+def _decode_text(cfg, one_chip, cache_len):
+    """The decode executable of ``cfg`` for 16 steps, as the chip's compiler
+    builds it, and its cache's shapes."""
+    model = build_model(cfg)
 
     def on_chip(tree):
         return jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), tree)
 
     params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    cache = on_chip(jax.eval_shape(lambda: model.init_cache(1, 2048)))
-    stacked = "bf16[%s]" % ",".join(map(str, cache["k"].shape))
-    assert stacked == "bf16[28,1,8,2048,128]"
+    cache = on_chip(jax.eval_shape(lambda: model.init_cache(1, cache_len)))
     tok = _spec((1, 1), jnp.int32, one_chip)
-    text = model.decode_tokens.lower(params, cache, tok, n_steps=16).compile().as_text()
+    return model.decode_tokens.lower(params, cache, tok, n_steps=16).compile().as_text(), cache
+
+
+def test_decode_loop_copies_no_whole_cache(one_chip):
+    """qwen3-0.6b's decode at 2,048 cache slots, as the chip's compiler
+    builds it: the layer scan carries the stacked cache, so no while body
+    (nor anything it calls) copies the whole stacked cache. The copies at
+    the executable's entry and exit run once per request."""
+    text, cache = _decode_text(QWEN, one_chip, 2048)
+    stacked = "bf16[%s]" % ",".join(map(str, cache["k"]["full"].shape))
+    assert stacked == "bf16[28,1,8,2048,128]"
+    _assert_no_loop_copies(text, [stacked])
+
+
+def _mellum2_one_chip():
+    """mellum2-12b-a2.5b at its published widths with one chip's 16 of 64
+    experts."""
+    full = get_config("mellum2-12b-a2.5b")
+    return dataclasses.replace(full, moe=dataclasses.replace(full.moe, n_held=16))
+
+
+def test_mellum2_decode_loop_copies_no_cache_stack(one_chip):
+    """mellum2-12b-a2.5b's decode with one chip's experts, at 4,096 cache
+    slots: the period scan carries both cache stacks, the ring of the 21
+    window layers and the 7 full layers' stack, and no loop body copies
+    either whole."""
+    text, cache = _decode_text(_mellum2_one_chip(), one_chip, 4096)
+    stacks = ["bf16[%s]" % ",".join(map(str, cache["k"][kind].shape)) for kind in ("window", "full")]
+    assert stacks == ["bf16[21,1,4,1024,128]", "bf16[7,1,4,4096,128]"]
+    _assert_no_loop_copies(text, stacks)
+
+
+def _assert_no_loop_copies(text: str, stacked: list[str]) -> None:
     comps = _computations(text)
     todo = [m.group(1) for line in text.splitlines() if (m := WHILE_BODY.search(line))]
     assert len(todo) >= 2, "the step loop and the layer loop"
@@ -148,5 +177,30 @@ def test_decode_loop_copies_no_whole_cache(one_chip):
         seen.add(name)
         for line in comps[name]:
             todo += CALLED.findall(line)
-            assert not re.search(re.escape(stacked) + r"\{[^}]*\} copy(?:-start)?\(", line), (
-                name, line[:200])
+            for shape in stacked:
+                assert not re.search(re.escape(shape) + r"\{[^}]*\} copy(?:-start)?\(", line), (
+                    name, line[:200])
+
+
+def test_mellum2_decode_reads_each_expert_where_it_lies(one_chip):
+    """The decode's loop over the held picks reads each picked expert's
+    matrices from the stack of every layer's experts: no loop body, nor a
+    computation it runs (fused computations aside: their values are not
+    written out), makes a layer's 16 experts or one expert's matrix."""
+    text, _ = _decode_text(_mellum2_one_chip(), one_chip, 4096)
+    comps = _computations(text)
+    ran = re.compile(r"\b(?:body|condition|to_apply)=%([\w.\-]+)")
+    todo = [m.group(1) for line in text.splitlines() if (m := WHILE_BODY.search(line))]
+    assert len(todo) >= 2, "the step loop and the layer loop"
+    experts = re.compile(r"= bf16\[(?:16,)?(?:2304,896|896,2304)\]")
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            todo += ran.findall(line)
+            assert not experts.search(line), (name, line[:200])
+    assert any("moe_experts" in line for name in seen for line in comps[name]), \
+        "the loop over the held picks runs inside the decode loops"
