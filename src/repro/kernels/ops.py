@@ -4,7 +4,8 @@ Handles padding to block multiples, dtype management, and the interpret-mode
 decision: Pallas compiles these kernels for the TPU only, so a kernel whose
 operands live on a TPU runs compiled (Mosaic) and anywhere else its body
 runs in the Pallas interpreter. :func:`runs_interpreted` makes that choice
-per call from the operands, so importing this module touches no device.
+per call from the operands. Importing this module touches no device and
+loads no Pallas: each wrapper imports its kernel when first called.
 ``use_pallas=False`` calls the pure-jnp oracle instead (used inside pjit'd
 model code where a CPU-interpreted pallas_call cannot be SPMD-partitioned).
 Shapes the attention kernels cannot tile raise; they are never handed to the
@@ -18,9 +19,6 @@ import jax
 import jax.numpy as jnp
 
 from . import ref as _ref
-from .decode_attention import decode_attention as _decode_attention
-from .flash_attention import flash_attention as _flash_attention
-from .matmul_probe import matmul as _matmul
 
 
 def runs_interpreted(x: jax.Array) -> bool:
@@ -53,6 +51,8 @@ def matmul(
     """Tiled matmul; pads M/N/K up to block multiples then slices back."""
     if not use_pallas:
         return _ref.matmul_ref(a, b)
+    from .matmul_probe import matmul as _matmul
+
     m, k = a.shape
     _, n = b.shape
     bm, bn, bk = (min(block_m, m), min(block_n, n), min(block_k, k))
@@ -73,12 +73,15 @@ def flash_attention(
     *,
     causal: bool = True,
     sm_scale: float | None = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: int | None = None,
+    block_k: int | None = None,
     use_pallas: bool = True,
 ) -> jax.Array:
+    """Blocks default to ``flash_attention.flash_blocks`` of the shapes."""
     if not use_pallas:
         return _ref.attention_ref(q, k, v, causal=causal, sm_scale=sm_scale)
+    from .flash_attention import flash_attention as _flash_attention
+
     # ragged lengths raise in the kernel: padding needs length masking
     return _flash_attention(
         q, k, v, causal=causal, sm_scale=sm_scale, block_q=block_q,
@@ -98,6 +101,8 @@ def decode_attention(
 ) -> jax.Array:
     if not use_pallas:
         return _ref.decode_attention_ref(q, k_cache, v_cache, lengths, sm_scale=sm_scale)
+    from .decode_attention import decode_attention as _decode_attention
+
     # a cache length that block_k does not divide raises in the kernel
     return _decode_attention(
         q, k_cache, v_cache, lengths, sm_scale=sm_scale, block_k=block_k,

@@ -1,7 +1,16 @@
 """GQA attention: prefill (full / sliding-window / causal) and single-token
-decode against a KV cache. Pure-jnp paths are the default inside pjit (a
-CPU-interpreted pallas_call cannot be SPMD-partitioned); the Pallas kernels
-are the TPU path and are validated separately in tests.
+decode against a KV cache.
+
+The transformer's serving prefill (``flash=True``) runs a full causal
+layer (no window, no cross attention) through the Pallas flash kernel
+(``kernels/flash_attention.py``) where it compiles for a TPU and no mesh of
+several devices partitions it. Everywhere else it takes the jnp reference:
+on the CPU, where the kernel would run interpreted; in a sharded prefill,
+which a pallas_call cannot be partitioned into; and in the training
+forward, which is differentiated and the kernel has no reverse-mode rule.
+Window layers take the banded path once the prompt outgrows the window,
+cross attention and non-causal calls the reference, and decode the jnp
+reference on every backend.
 """
 from __future__ import annotations
 
@@ -11,7 +20,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import shard
+from repro.distributed.sharding import current_mesh, shard
+from repro.kernels import ops
 from repro.kernels import ref as kref
 from .layers import normal_init, rms_norm, rope
 
@@ -124,9 +134,13 @@ def prefill_attention(
     use_rope: bool = True,
     cross_kv: Optional[tuple[jax.Array, jax.Array]] = None,  # (B, S_kv, K, hd)
     yarn=None,
+    flash: bool = False,
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
     """Returns (out (B,S,d), (k_cache, v_cache) in (B,K,S,hd) layout).
-    ``yarn`` (a ``RopeConfig``) switches RoPE to YaRN (``layers.rope``)."""
+    ``yarn`` (a ``RopeConfig``) switches RoPE to YaRN (``layers.rope``).
+    ``flash``: a serving caller's full causal layer may take the flash
+    kernel (``_serves_flash``); a forward that is differentiated keeps the
+    reference."""
     if cross_kv is None:
         q, k, v = _project_qkv(p, x, positions, rope_theta, eps, use_rope, yarn)
     else:
@@ -141,7 +155,9 @@ def prefill_attention(
     kh = k.transpose(0, 2, 1, 3)
     vh = v.transpose(0, 2, 1, 3)
     bq = _band_block(qh.shape[2], window) if causal and cross_kv is None else None
-    if bq:
+    if flash and causal and window is None and cross_kv is None and _serves_flash(qh):
+        out = _flash_prefill(qh, kh, vh)
+    elif bq:
         out = _banded_attention(qh, kh, vh, window, bq)
     else:
         out = kref.attention_ref(qh, kh, vh, causal=causal, window=window)
@@ -149,6 +165,28 @@ def prefill_attention(
     y = jnp.einsum("bshk,hkd->bsd", out, p.wo)
     y = shard(y, "batch", "seq", None)
     return y, (kh, vh)
+
+
+def _serves_flash(q: jax.Array) -> bool:
+    """A full causal prefill runs the flash kernel where it compiles for a
+    TPU and no mesh of several devices would have to partition it."""
+    mesh = current_mesh()
+    return not ops.runs_interpreted(q) and (mesh is None or mesh.size == 1)
+
+
+def _flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
+    """Causal self-attention by the flash kernel, q: (B, H, S, hd), k/v:
+    (B, K, S, hd). A prompt the blocks do not divide is padded at the tail
+    and the output sliced back: under the causal mask no real query sees a
+    padded key, so the padding changes nothing. The kernel picks its blocks
+    from the padded shapes."""
+    from repro.kernels.flash_attention import seq_multiple
+
+    S = q.shape[2]
+    pad = -S % seq_multiple(S)
+    if pad:
+        q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0))) for a in (q, k, v))
+    return ops.flash_attention(q, k, v, causal=True)[:, :, :S]
 
 
 def _band_block(S: int, window: Optional[int]) -> Optional[int]:
@@ -291,7 +329,6 @@ def decode_attention_step(
     qh = q.transpose(0, 2, 1, 3)              # (B, H, 1, hd)
     k_new = k_new.transpose(0, 2, 1, 3)       # (B, K, 1, hd)
     v_new = v_new.transpose(0, 2, 1, 3)
-    from repro.distributed.sharding import current_mesh
     if (
         DECODE_ATTN_MODE == "shard_map"
         and update_cache

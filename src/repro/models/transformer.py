@@ -95,12 +95,16 @@ def _attn_args(cfg: ArchConfig, kind: str) -> dict:
 # executable's device time by them. Inside "attn" the layer's kind
 # ("window" or "full") names its attention, inside "mlp" a MoE names its
 # "moe_route", "moe_experts" and "moe_combine".
-def _layer_prefill(cfg: ArchConfig, p, x, positions, kind, mlp):
+def _layer_prefill(cfg: ArchConfig, p, x, positions, kind, serve: bool):
+    """The training forward's layer, or with ``serve`` the serving
+    prefill's: the MoE drops no token and a full causal layer may take the
+    flash kernel, which has no reverse-mode rule."""
+    mlp = _mlp_serve if serve else _mlp_apply
     with jax.named_scope("attn"):
         with jax.named_scope(kind):
             h, (k, v) = prefill_attention(
                 p["attn"], rms_norm(x, p["ln1"], cfg.norm_eps), positions, causal=True,
-                **_attn_args(cfg, kind),
+                flash=serve, **_attn_args(cfg, kind),
             )
         x = x + h
     with jax.named_scope("mlp"):
@@ -183,7 +187,7 @@ def forward(
         auxs = []
         for j, kind in enumerate(cfg.period):
             p = jax.tree.map(lambda a: a[j], block)
-            x, _, aux = _layer_prefill(cfg, p, x, positions, kind, _mlp_apply)
+            x, _, aux = _layer_prefill(cfg, p, x, positions, kind, serve=False)
             auxs.append(aux)
         return x, sum(auxs[1:], auxs[0])
 
@@ -237,7 +241,7 @@ def prefill(cfg: ArchConfig, params, tokens: jax.Array, cache):
         kv = {kind: [] for kind in _kinds(cfg)}
         for j, kind in enumerate(cfg.period):
             p = _layer(cfg, params["layers"], i, j)
-            x, k_v, _ = _layer_prefill(cfg, p, x, positions, kind, _mlp_serve)
+            x, k_v, _ = _layer_prefill(cfg, p, x, positions, kind, serve=True)
             kv[kind].append(k_v)
         # a kind's layers of this period, stacked where there are several
         return x, {kind: l[0] if len(l) == 1 else jax.tree.map(lambda *a: jnp.stack(a), *l)

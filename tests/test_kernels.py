@@ -6,7 +6,7 @@ import pytest
 
 from repro.kernels import ops, ref
 from repro.kernels.decode_attention import decode_attention
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import flash_attention, flash_blocks
 from repro.kernels.matmul_probe import matmul
 
 
@@ -42,6 +42,9 @@ def test_matmul_padding_path():
     (1, 4, 4, 128, 64),     # MHA
     (2, 8, 2, 256, 64),     # GQA 4:1
     (2, 4, 1, 128, 128),    # MQA
+    (1, 16, 8, 256, 128),   # qwen3-0.6b's heads
+    (1, 32, 32, 256, 96),   # phi3-mini-3.8b's heads
+    (1, 32, 4, 256, 128),   # mellum2-12b-a2.5b's full layers
 ])
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_attention_sweep(batch, qh, kvh, seq, d, causal):
@@ -61,6 +64,46 @@ def test_flash_attention_bf16():
     want = ref.attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(want, np.float32),
                                rtol=5e-2, atol=5e-2)
+
+
+@pytest.mark.parametrize("block_q,block_k", [(64, 128), (128, 64), (32, 128)])
+def test_flash_attention_rectangular_blocks(block_q, block_k):
+    """block_q != block_k: the causal skip and the clamped K/V index map
+    name the right blocks on both sides of the diagonal."""
+    q = _rand((1, 8, 256, 64), jnp.float32, 0)
+    k = _rand((1, 2, 256, 64), jnp.float32, 1)
+    v = _rand((1, 2, 256, 64), jnp.float32, 2)
+    out = flash_attention(q, k, v, causal=True, block_q=block_q, block_k=block_k,
+                          interpret=True)
+    want = ref.attention_ref(q, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("qh,kvh,d", [(16, 8, 128), (32, 32, 96), (32, 4, 128)])
+def test_flash_attention_bf16_served_heads(qh, kvh, d):
+    """bf16 q/k/v at the served models' heads, with the blocks the kernel
+    picks from the shapes (several of them along 1,024 tokens)."""
+    q = _rand((1, qh, 1024, d), jnp.bfloat16, 0)
+    k = _rand((1, kvh, 1024, d), jnp.bfloat16, 1)
+    v = _rand((1, kvh, 1024, d), jnp.bfloat16, 2)
+    out = flash_attention(q, k, v, causal=True, interpret=True)
+    want = ref.attention_ref(q, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(want, np.float32),
+                               rtol=5e-2, atol=5e-2)
+
+
+@pytest.mark.parametrize("seq,group,d,want", [
+    (4096, 2, 128, (512, 512)),    # qwen3-0.6b
+    (1024, 1, 96, (512, 512)),     # phi3-mini-3.8b
+    (256, 1, 96, (256, 256)),
+    (4096, 8, 128, (128, 512)),    # mellum2-12b-a2.5b's full layers
+    (300, 2, 128, (512, 512)),     # padded to one block
+    (8, 4, 64, (128, 128)),
+])
+def test_flash_blocks_from_shapes(seq, group, d, want):
+    bq, bk = flash_blocks(seq, group, d)
+    assert (bq, bk) == want
+    assert group * bq * (bk + d) * 4 <= 2.5 * 2**20
 
 
 @pytest.mark.parametrize("batch,qh,kvh,S,d,block_k", [

@@ -66,6 +66,57 @@ def test_flash_attention_compiles_at_qwen3_heads(one_chip, batch, seq):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("arch,seq", [("phi3-mini-3.8b", 1024), ("mellum2-12b-a2.5b", 4096)])
+def test_flash_attention_compiles_at_served_heads(one_chip, arch, seq):
+    """phi3's 32 heads of 96 and mellum2's 32/4 heads of 128, with the
+    blocks the kernel picks from the shapes."""
+    cfg = get_config(arch)
+    q = _spec((1, cfg.n_heads, seq, cfg.head_dim), jnp.bfloat16, one_chip)
+    kv = _spec((1, cfg.n_kv_heads, seq, cfg.head_dim), jnp.bfloat16, one_chip)
+    text = flash_attention.lower(q, kv, kv, causal=True,
+                                 interpret=False).compile().as_text()
+    assert re.search(r"%prefill_flash\S* = .*custom_call_target=\"tpu_custom_call\"", text)
+
+
+def test_qwen3_prefill_runs_the_flash_kernel(one_chip, monkeypatch):
+    """qwen3-0.6b's prefill of 2,048 tokens as the chip's compiler builds
+    it, with the kernel compiled as on the chip: one ``prefill_flash`` call
+    (in the layer scan's body) and no f32 (..., S, S) scores."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "runs_interpreted", lambda x: False)
+    model = build_model(QWEN)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(lambda: model.init_cache(1, 4096)))
+    tokens = _spec((1, 2048), jnp.int32, one_chip)
+    text = model.prefill_jit.lower(params, {"tokens": tokens}, cache).compile().as_text()
+    assert len(re.findall(r"%prefill_flash\S* = .*custom-call\(", text)) == 1
+    assert not re.search(r"f32\[[0-9,]*2048,2048\]", text)
+
+
+def test_qwen3_training_step_compiles_without_the_kernel(one_chip, monkeypatch):
+    """The gradient of qwen3-0.6b's loss, 256 tokens, as the chip's compiler
+    builds it: the training forward is differentiated, so it keeps the
+    reference attention and no ``prefill_flash`` call."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "runs_interpreted", lambda x: False)
+    model = build_model(QWEN)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    tokens = _spec((1, 256), jnp.int32, one_chip)
+    grad = jax.jit(jax.grad(lambda p, b: model.loss(p, b)[0]))
+    text = grad.lower(params, {"tokens": tokens, "labels": tokens}).compile().as_text()
+    assert "prefill_flash" not in text
+
+
 def test_decode_attention_compiles_at_qwen3_heads(one_chip):
     batch, cache_len, hd = 4, 2048, QWEN.head_dim
     q = _spec((batch, QWEN.n_heads, 1, hd), jnp.bfloat16, one_chip)
