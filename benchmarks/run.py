@@ -1,5 +1,5 @@
 """Benchmark harness: one entry per paper figure/table + kernel micro +
-roofline aggregation + the vectorized grid sweep. Prints
+the vectorized grid sweep. Prints
 ``name,us_per_call,derived`` CSV rows per the repo convention, then
 detailed per-figure tables.
 
@@ -97,7 +97,7 @@ def main() -> None:
 
     from . import (diurnal_sweep, fault_sweep, figs, fleet_sweep,
                    grid_sweep, kernels_micro, openloop_sweep,
-                   pipeline_sweep, roofline_table, workflow_sweep)
+                   pipeline_sweep, workflow_sweep)
 
     benches = {
         "workflow_sweep": workflow_sweep.workflow_sweep,
@@ -130,7 +130,6 @@ def main() -> None:
         "ablation_stale_threshold": figs.ablation_stale_threshold,
         "ablation_online_controller": figs.ablation_online_controller,
         "kernel_micro": kernels_micro.kernel_micro,
-        "roofline_table": roofline_table.roofline_table,
     }
     selected = [s for s in args.only.split(",") if s] or list(benches)
     unknown = [s for s in selected if s not in benches]

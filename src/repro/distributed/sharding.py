@@ -1,12 +1,11 @@
 """Logical-axis sharding: models annotate tensors with *logical* axis names;
 the launcher binds logical names to physical mesh axes. Outside a mesh
-context every annotation is a no-op, so the same model code runs in CPU
-smoke tests and in the 512-device dry-run.
+context every annotation is a no-op, so the same model code runs on one
+device and under a mesh (``launch/shardings.py``'s rules).
 
 Logical axes used by the model zoo:
   "batch"    — data-parallel batch dim            -> ("pod", "data")
-  "seq"      — sequence/context dim               -> None (baseline), "data"
-               for the long-context flash-decode hillclimb
+  "seq"      — sequence/context dim               -> None
   "model"    — hidden size / head / expert shards -> "model"
   "vocab"    — embedding vocab shard              -> "model"
   "expert"   — MoE expert dim                     -> "model"

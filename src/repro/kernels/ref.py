@@ -2,7 +2,8 @@
 
 These are the ground truth the kernels are validated against
 (tests/test_kernels.py sweeps shapes and dtypes with assert_allclose), and
-the fallback path used by the models during CPU smoke tests and dry-runs.
+the path the models take wherever a kernel is not wired in or would run
+interpreted.
 """
 from __future__ import annotations
 

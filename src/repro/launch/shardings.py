@@ -1,27 +1,23 @@
 """Per-architecture sharding rules.
 
 Logical->physical rules are computed per arch (divisibility-guarded), and
-parameter/optimizer/cache/batch PartitionSpecs are derived from pytree
-paths. Anything that cannot shard cleanly falls back to replication — the
-roofline table then shows the cost, and the hillclimb (§Perf) fixes the
-pairs where it matters.
+parameter/cache/batch PartitionSpecs are derived from pytree paths.
+Anything that cannot shard cleanly falls back to replication.
+``tests/test_tpu_compile.py`` compiles the serving and training steps under
+these rules for a described v5e:2x2, and ``tests/test_shardings.py`` runs
+decode under them on a four-device mesh.
 """
 from __future__ import annotations
 
 from typing import Any, Optional
 
 import jax
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 
 MODEL_AXIS = "model"
 DATA_AXES = ("pod", "data")
-
-# §Perf pick-3 iter-4: shard KV caches along LENGTH (flash-decode shard_map
-# path). Set by dryrun --decode-attn shard_map.
-FORCE_SEQ_SHARD_CACHE = False
 
 
 def _mesh_size(mesh: Mesh, axes) -> int:
@@ -141,9 +137,7 @@ def cache_spec(path: str, shape: tuple[int, ...], cfg: ArchConfig, mesh: Mesh,
         # score all-reduce — §Perf pick-3 iter-2: S-sharding made the
         # per-step cache update all-gather the whole cache); else the
         # cache LENGTH as last resort.
-        if FORCE_SEQ_SHARD_CACHE:
-            spec = P(None, data_axes, None, M, None)
-        elif cfg.n_kv_heads % msize == 0:
+        if cfg.n_kv_heads % msize == 0:
             spec = P(None, data_axes, M, None, None)
         elif cfg.head_dim % msize == 0:
             spec = P(None, data_axes, None, None, M)
@@ -177,59 +171,10 @@ def batch_spec(path: str, shape: tuple[int, ...], mesh: Mesh, data_axes) -> P:
     return _guard(spec, shape, mesh)
 
 
-def dp_only_rules(mesh: Mesh, global_batch: int | None = None) -> dict:
-    """Pure data-parallel logical rules: batch over as many mesh axes as its
-    size divides, no model parallelism. The §Perf pick-2 optimization for
-    small recurrent models (xlstm-1.3b) whose 4 heads cannot use a 16-way
-    model axis — model-parallel resharding was 92% of the baseline step."""
-    axes: list[str] = []
-    prod = 1
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    for a in mesh.axis_names:
-        if global_batch is not None and global_batch % (prod * sizes[a]) != 0:
-            break
-        axes.append(a)
-        prod *= sizes[a]
-    return {
-        "batch": tuple(axes) or None, "seq": None, "model": None,
-        "vocab": None, "expert": None, "ff": None, "heads": None,
-        "kv_heads": None, "state": None,
-    }
-
-
-def add_fsdp_axes(spec: P, shape: tuple[int, ...], mesh: Mesh, data_axes) -> P:
-    """ZeRO/FSDP: additionally shard a parameter (or optimizer-state leaf)
-    over the data axes on the first still-replicated dim that divides.
-    XLA re-gathers layer slices inside the scan (FSDP semantics)."""
-    if data_axes is None:
-        return spec
-    dsize = _mesh_size(mesh, data_axes)
-    entries = list(spec) + [None] * (len(shape) - len(list(spec)))
-    # never the leading (layer-stack) dim of scanned params: the scan's
-    # dynamic-slice over a sharded dim forces a FULL weight all-gather
-    # (measured: 108 s of ICI per step — §Perf pick-1 iter-2); walk from
-    # the trailing dims instead.
-    lo = 1 if len(shape) >= 3 else 0
-    for i in range(len(entries) - 1, lo - 1, -1):
-        if entries[i] is None and shape[i] % dsize == 0 and shape[i] >= dsize:
-            entries[i] = data_axes
-            return P(*entries)
-    return P(*entries)
-
-
-def tree_shardings(tree, spec_fn, mesh: Mesh):
-    """Map a pytree of ShapeDtypeStructs/arrays -> NamedSharding tree."""
-
-    def one(path, leaf):
-        spec = spec_fn(_path_str(path), tuple(leaf.shape), mesh)
-        return NamedSharding(mesh, spec)
-
-    return jax.tree_util.tree_map_with_path(one, tree)
-
-
 def shard_inputs(cfg: ArchConfig, mesh: Mesh, specs: dict[str, Any]):
-    """Attach NamedShardings to input_specs output. Returns
-    (batch_sds, cache_sds) with .sharding set."""
+    """Attach NamedShardings to ``{"batch": ..., "cache": ... or None}``,
+    pytrees of ShapeDtypeStructs. Returns (batch_sds, cache_sds) with
+    .sharding set."""
     data_axes = tuple(a for a in DATA_AXES if a in mesh.axis_names)
     data_axes = data_axes if data_axes else None
 
@@ -253,23 +198,11 @@ def shard_inputs(cfg: ArchConfig, mesh: Mesh, specs: dict[str, Any]):
     return batch, cache
 
 
-def shard_params_like(params_shape, cfg: ArchConfig, mesh: Mesh,
-                      *, fsdp: bool = False, replicate: bool = False):
-    """ShapeDtypeStruct param tree with NamedShardings attached.
-    fsdp: additionally shard over the data axes (ZeRO-style).
-    replicate: no sharding at all (the dp-only mode)."""
-    data_axes = tuple(a for a in DATA_AXES if a in mesh.axis_names) or None
+def shard_params_like(params_shape, cfg: ArchConfig, mesh: Mesh):
+    """ShapeDtypeStruct param tree with NamedShardings attached."""
 
     def one(path, leaf):
-        if replicate:
-            spec = P(*([None] * len(leaf.shape)))
-        else:
-            spec = param_spec(_path_str(path), tuple(leaf.shape), cfg, mesh)
-            if fsdp:
-                spec = _guard(
-                    add_fsdp_axes(spec, tuple(leaf.shape), mesh, data_axes),
-                    tuple(leaf.shape), mesh,
-                )
+        spec = param_spec(_path_str(path), tuple(leaf.shape), cfg, mesh)
         return jax.ShapeDtypeStruct(
             leaf.shape, leaf.dtype, sharding=NamedSharding(mesh, spec)
         )

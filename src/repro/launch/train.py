@@ -1,9 +1,8 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> [...]``.
 
-On this CPU container it trains the reduced (smoke) variant of the chosen
-architecture on the synthetic token stream; on a real TPU fleet the same
-entry point takes ``--full --mesh pod|multipod`` and builds the production
-mesh + shardings that the dry-run validates.
+It trains the reduced (smoke) variant of the chosen architecture on the
+synthetic token stream, or the published widths with ``--full``, on one
+device.
 """
 from __future__ import annotations
 
@@ -22,8 +21,8 @@ def main() -> None:
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--peak-lr", type=float, default=1e-3)
     ap.add_argument("--full", action="store_true",
-                    help="full config (requires a TPU fleet; CPU default is "
-                         "the reduced smoke variant)")
+                    help="published widths (default: the reduced smoke "
+                         "variant)")
     args = ap.parse_args()
 
     cfg = get_config(args.arch) if args.full else get_smoke_config(args.arch)

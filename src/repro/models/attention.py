@@ -9,8 +9,12 @@ on the CPU, where the kernel would run interpreted; in a sharded prefill,
 which a pallas_call cannot be partitioned into; and in the training
 forward, which is differentiated and the kernel has no reverse-mode rule.
 Window layers take the banded path once the prompt outgrows the window,
-cross attention and non-causal calls the reference, and decode the jnp
-reference on every backend.
+cross attention and non-causal calls the reference.
+
+Decode has one path on every backend and mesh: the new row is written in
+place (one ``dynamic_update_slice`` for one sequence, one clamped scatter
+for a batch, a per-layer ``vmap`` where the cache is not a stack) and the
+step attends by the jnp reference, ``kref.decode_attention_ref``.
 """
 from __future__ import annotations
 
@@ -24,24 +28,6 @@ from repro.distributed.sharding import current_mesh, shard
 from repro.kernels import ops
 from repro.kernels import ref as kref
 from .layers import normal_init, rms_norm, rope
-
-# Decode attention strategy:
-#   "local" (default) — jnp reference attention; SPMD derives collectives.
-#   "shard_map" — §Perf pick-3 iter-4: explicit flash-decode. The KV cache
-#       is sharded along its LENGTH over the model axis; each shard computes
-#       local masked scores + LSE, combines with pmax/psum (KBs of wire
-#       instead of the 512 MiB/layer cache all-gather XLA chose), and the
-#       new token row is written locally by exactly one shard.
-DECODE_ATTN_MODE = "local"
-
-# KV-cache update strategy for decode:
-#   "scatter" (default) — per-sequence dynamic_update_slice; touches only the
-#       written row (O(hd) bytes/seq). The beyond-paper optimization from
-#       EXPERIMENTS.md §Perf pick-3: the one-hot path rewrites the ENTIRE
-#       cache every step (~35 GiB/dev/step for llama3.2-1b decode_32k).
-#   "onehot" — masked full-cache blend; the paper-faithful baseline we
-#       measured first (kept selectable for the §Perf record).
-CACHE_UPDATE_MODE = "scatter"
 
 
 def _layer_of(cache: jax.Array, layer: Optional[jax.Array]) -> jax.Array:
@@ -57,9 +43,6 @@ def _write_cache_row(cache: jax.Array, new: jax.Array, slot: jax.Array,
     """cache: (B, K, S, hd), or with ``layer`` the stacked (L, B, K, S, hd)
     whose layer ``layer`` is written; new: (B, K, 1, hd); slot: (B,) int32."""
     if layer is not None:
-        if CACHE_UPDATE_MODE == "onehot":
-            return jax.lax.dynamic_update_index_in_dim(
-                cache, _write_cache_row(_layer_of(cache, layer), new, slot), layer, 0)
         rows = new[:, :, 0].astype(cache.dtype)  # (B, K, hd), written in place
         if cache.shape[1] == 1:
             # one sequence: a dynamic_update_slice, which on TPU leaves the
@@ -70,9 +53,6 @@ def _write_cache_row(cache: jax.Array, new: jax.Array, slot: jax.Array,
         # out-of-range slots clamp, as dynamic_update_slice's do
         return cache.at[layer, jnp.arange(cache.shape[1]), :, slot, :].set(
             rows, mode="clip", indices_are_sorted=True, unique_indices=True)
-    if CACHE_UPDATE_MODE == "onehot":
-        oh = jax.nn.one_hot(slot, cache.shape[2], dtype=cache.dtype)  # (B, S)
-        return cache * (1.0 - oh[:, None, :, None]) + new * oh[:, None, :, None]
 
     def one(c, n, s):  # (K, S, hd), (K, 1, hd), scalar
         return jax.lax.dynamic_update_slice(c, n.astype(c.dtype), (0, s, 0))
@@ -223,78 +203,6 @@ def _banded_attention(q: jax.Array, k: jax.Array, v: jax.Array, window: int,
     return out.reshape(B, H, S, d).astype(q.dtype)
 
 
-def _sharded_flash_decode(
-    q: jax.Array,        # (B, H, 1, hd)
-    k_cache: jax.Array,  # (B, K, S, hd) — S sharded over "model"
-    v_cache: jax.Array,
-    k_new: jax.Array,    # (B, K, 1, hd)
-    v_new: jax.Array,
-    slot: jax.Array,     # (B,) global write position
-    valid: jax.Array,    # (B,) valid prefix length after the write
-    sm_scale: float,
-):
-    """Flash-decode over a length-sharded cache via shard_map."""
-    from jax.sharding import PartitionSpec as P
-
-    from repro.distributed.sharding import current_mesh, logical_to_spec
-
-    mesh = current_mesh()
-    dp = logical_to_spec("batch")[0]  # physical axes for batch (or None)
-
-    def inner(q, kc, vc, nk, nv, slot, valid):
-        idx = jax.lax.axis_index("model")
-        B, K, S_loc, hd = kc.shape
-        H = q.shape[1]
-        G = H // K
-        start = idx * S_loc
-        ls = slot - start  # local write position, (B,)
-
-        def write(c, n):
-            inb = (ls >= 0) & (ls < S_loc)
-            lsc = jnp.clip(ls, 0, S_loc - 1)
-            upd = jax.vmap(
-                lambda cc, nn, s: jax.lax.dynamic_update_slice(
-                    cc, nn.astype(cc.dtype), (0, s, 0))
-            )(c, n, lsc)
-            return jnp.where(inb[:, None, None, None], upd, c)
-
-        kc = write(kc, nk)
-        vc = write(vc, nv)
-        qg = q.reshape(B, K, G, hd)
-        s = jnp.einsum("bkgd,bksd->bkgs", qg, kc,
-                       preferred_element_type=jnp.float32) * sm_scale
-        k_pos = start + jnp.arange(S_loc)
-        s = jnp.where(k_pos[None, None, None, :] < valid[:, None, None, None],
-                      s, -1e30)
-        m_loc = jnp.max(s, axis=-1)                       # (B, K, G)
-        m = jax.lax.pmax(m_loc, "model")
-        p = jnp.exp(s - m[..., None])
-        l = jax.lax.psum(jnp.sum(p, axis=-1), "model")
-        o = jnp.einsum("bkgs,bksd->bkgd", p.astype(vc.dtype), vc,
-                       preferred_element_type=jnp.float32)
-        o = jax.lax.psum(o, "model")
-        o = o / jnp.maximum(l, 1e-20)[..., None]
-        return o.reshape(B, H, 1, hd).astype(q.dtype), kc, vc
-
-    bspec = lambda *rest: P(dp, *rest)  # noqa: E731
-    out, kc, vc = jax.shard_map(
-        inner,
-        mesh=mesh,
-        in_specs=(
-            bspec(None, None, None),            # q replicated over model
-            bspec(None, "model", None),         # cache length-sharded
-            bspec(None, "model", None),
-            bspec(None, None, None),
-            bspec(None, None, None),
-            P(dp), P(dp),
-        ),
-        out_specs=(bspec(None, None, None), bspec(None, "model", None),
-                   bspec(None, "model", None)),
-        check_vma=False,
-    )(q, k_cache, v_cache, k_new, v_new, slot, valid)
-    return out, kc, vc
-
-
 def decode_attention_step(
     p: AttnParams,
     x: jax.Array,                 # (B, 1, d) current token activations
@@ -329,28 +237,6 @@ def decode_attention_step(
     qh = q.transpose(0, 2, 1, 3)              # (B, H, 1, hd)
     k_new = k_new.transpose(0, 2, 1, 3)       # (B, K, 1, hd)
     v_new = v_new.transpose(0, 2, 1, 3)
-    if (
-        DECODE_ATTN_MODE == "shard_map"
-        and update_cache
-        and current_mesh() is not None
-        and "model" in current_mesh().axis_names
-    ):
-        import math as _math
-
-        slot = lengths % S if window is not None else lengths
-        valid = jnp.minimum(lengths + 1, S)
-        out, kc, vc = _sharded_flash_decode(
-            qh, _layer_of(k_cache, layer), _layer_of(v_cache, layer),
-            k_new, v_new, slot, valid, sm_scale=1.0 / _math.sqrt(qh.shape[-1]),
-        )
-        if layer is None:
-            k_cache, v_cache = kc, vc
-        else:
-            k_cache = jax.lax.dynamic_update_index_in_dim(k_cache, kc, layer, 0)
-            v_cache = jax.lax.dynamic_update_index_in_dim(v_cache, vc, layer, 0)
-        out = out.transpose(0, 2, 1, 3)
-        y = jnp.einsum("bshk,hkd->bsd", out, p.wo)
-        return shard(y, "batch", None, None), k_cache, v_cache
     if update_cache:
         slot = lengths % S if window is not None else lengths
         with jax.named_scope("kv_write"):

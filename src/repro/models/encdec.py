@@ -2,7 +2,7 @@
 
 The modality frontend (mel-spectrogram + conv1d feature extractor) is the
 permitted STUB: inputs are precomputed frame embeddings (B, frames, d_model)
-supplied by ``input_specs``. Everything downstream — the bidirectional
+supplied by the caller. Everything downstream — the bidirectional
 encoder, the causal decoder with cross-attention, KV caches for decode — is
 implemented. RoPE replaces Whisper's absolute embeddings (TPU-idiomatic;
 noted in DESIGN.md §8).

@@ -273,10 +273,6 @@ def slstm_scan(
 
     def body(carry, xg):
         c, n, h_prev, m = carry
-        # NOTE (§Perf pick-2): a with_sharding_constraint here does NOT stop
-        # XLA from inserting per-time-step backward all-reduces (measured:
-        # no change); the working fix is running this whole cell under
-        # shard_map — see xlstm._slstm_scan_dispatch.
         # gate pre-activations: input part + recurrent part
         rec = jnp.einsum("bhp,ghpr->gbhr", h_prev, R.astype(jnp.float32))
         zt = jnp.tanh(xg[:, 0].astype(jnp.float32) + rec[0])

@@ -118,56 +118,11 @@ def _slstm_gates(cfg, p, x):
 
 def slstm_block(cfg: ArchConfig, p, x, *, state=None):
     xg = _slstm_gates(cfg, p, x)
-    h, new_state = _slstm_scan_dispatch(xg, p["R"], state)
+    h, new_state = slstm_scan(xg, p["R"], state=state)
     B, S = x.shape[:2]
     h = h.reshape(B, S, -1).astype(x.dtype)
     h = rms_norm(h, p["hnorm"], cfg.norm_eps)
     return x + h @ p["w_out"], new_state
-
-
-# §Perf pick-2 knob: run the sLSTM cell under shard_map (batch-local, no
-# partitioner-inserted per-step collectives). Off by default so baseline
-# measurements stay baseline; enabled by dryrun --slstm-shard-map.
-SLSTM_SHARD_MAP = False
-
-
-def _slstm_scan_dispatch(xg, R, state):
-    """Run the sequential sLSTM cell under shard_map when a mesh is active:
-    the cell is purely batch-parallel (R replicated), so making each device
-    run its batch shard locally removes the per-time-step all-reduces XLA's
-    SPMD partitioner otherwise inserts in the backward-through-time loop
-    (§Perf pick-2: 6 blocks x 4096 steps x 16.8 MB wire)."""
-    from jax.sharding import PartitionSpec as P
-
-    from repro.distributed.sharding import current_mesh, logical_to_spec
-
-    mesh = current_mesh()
-    B = xg.shape[0]
-    dp = logical_to_spec("batch")[0] if mesh is not None else None
-    dp_size = 1
-    if mesh is not None and dp is not None:
-        for a in ((dp,) if isinstance(dp, str) else dp):
-            dp_size *= mesh.shape[a]
-    if (not SLSTM_SHARD_MAP or mesh is None or dp is None or B % dp_size
-            or dp_size == 1):
-        return slstm_scan(xg, R, state=state)
-    if state is None:
-        Bsz, _, _, H, Pd = xg.shape
-        z0 = jnp.zeros((Bsz, H, Pd), jnp.float32)
-        state = (z0, z0, z0, jnp.full((Bsz, H, Pd), -1e30, jnp.float32))
-    bspec = P(dp, None, None)
-
-    def inner(xg, R, state):
-        return slstm_scan(xg, R, state=state)
-
-    return jax.shard_map(
-        inner,
-        mesh=mesh,
-        in_specs=(P(dp, None, None, None, None), P(None, None, None, None),
-                  (bspec, bspec, bspec, bspec)),
-        out_specs=(P(dp, None, None, None), (bspec, bspec, bspec, bspec)),
-        check_vma=False,
-    )(xg, R, state)
 
 
 # ---------------------------------------------------------------------------

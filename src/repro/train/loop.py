@@ -1,7 +1,6 @@
 """Training loop: jit'd train_step factory + a simple host loop with
-checkpointing. Used by examples/train_lm.py and the per-arch smoke tests;
-the same ``make_train_step`` output is what launch/dryrun.py lowers on the
-production mesh.
+checkpointing. Used by launch/train.py, examples/train_lm.py and the
+per-arch smoke tests.
 """
 from __future__ import annotations
 

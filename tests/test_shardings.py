@@ -1,4 +1,11 @@
-"""Sharding-rule logic (pure functions — no 512-device mesh needed)."""
+"""Sharding-rule logic (pure functions on a one-device mesh), and decode
+run under the rules on a mesh of four host devices."""
+import json
+import os
+import pathlib
+import subprocess
+import sys
+
 import jax
 import pytest
 from jax.sharding import AxisType, PartitionSpec as P
@@ -6,10 +13,8 @@ from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs.registry import get_config
 from repro.distributed.sharding import use_mesh, shard, logical_to_spec
 from repro.launch.shardings import (
-    add_fsdp_axes,
     batch_spec,
     cache_spec,
-    dp_only_rules,
     make_rules,
     param_spec,
 )
@@ -50,19 +55,6 @@ def test_guard_drops_indivisible():
     # the axis size doesn't divide; with axis size 1 everything divides.
     spec = param_spec("embed", (51865, 768), cfg, mesh16)
     assert spec == P("model", None)  # size-1 axis always divides
-
-
-def test_fsdp_never_shards_layer_dim():
-    spec = add_fsdp_axes(P(None, None, "model", None), (88, 12288, 96, 128),
-                         MESH, ("data",))
-    assert spec[0] is None  # leading (layer) dim untouched
-    assert ("data",) in tuple(spec) or "data" in tuple(spec)
-
-
-def test_dp_only_rules_cap_to_batch():
-    rules = dp_only_rules(MESH, global_batch=256)
-    assert rules["model"] is None and rules["ff"] is None
-    assert rules["batch"] is not None
 
 
 def test_cache_spec_kv_head_fallbacks():
@@ -110,3 +102,66 @@ def test_logical_to_spec_respects_rules():
     with use_mesh(mesh, {"heads": "model", "batch": None}):
         assert logical_to_spec("batch", None, "heads", None) == \
             P(None, None, "model", None)
+
+
+# Decode of a smoke qwen3 on one device and on a data 1 x model 4 mesh of
+# host devices, in a fresh interpreter: the device count is fixed when jax
+# first starts. Prints the sharded cache's spec, whether the tokens agree
+# and the largest difference of the final caches.
+_DECODE_ON_A_MESH = """
+import dataclasses, json, sys
+import jax, jax.numpy as jnp
+from jax.sharding import AxisType
+from repro.configs.registry import get_smoke_config
+from repro.distributed.sharding import use_mesh
+from repro.launch.shardings import make_rules, shard_inputs, shard_params_like
+from repro.models.model import build_model
+
+n_kv_heads, head_dim = map(int, sys.argv[1:])
+cfg = dataclasses.replace(get_smoke_config("qwen3-0.6b"),
+                          n_kv_heads=n_kv_heads, head_dim=head_dim)
+model = build_model(cfg)
+params = model.init(jax.random.PRNGKey(0))
+prompt = jax.random.randint(jax.random.PRNGKey(1), (1, 8), 0, cfg.vocab)
+_, cache = model.prefill_jit(params, {"tokens": prompt}, model.init_cache(1, 32))
+tok = prompt[:, -1:]
+toks, out = model.decode_tokens(params, cache, tok, n_steps=12)
+
+mesh = jax.make_mesh((1, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+put = lambda tree, like: jax.tree.map(lambda a, s: jax.device_put(a, s.sharding), tree, like)
+with use_mesh(mesh, make_rules(cfg, mesh)):
+    params_m = put(params, shard_params_like(params, cfg, mesh))
+    _, like = shard_inputs(cfg, mesh, {"batch": {}, "cache": cache})
+    cache_m = put(cache, like)
+    toks_m, out_m = build_model(cfg).decode_tokens(params_m, cache_m, tok, n_steps=12)
+print(json.dumps({
+    "spec": [a if a is None or isinstance(a, str) else list(a)
+             for a in cache_m["k"]["full"].sharding.spec],
+    "tokens_agree": bool((toks == toks_m).all()),
+    "max_diff": max(float(jnp.abs(a - b).max())
+                    for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(out_m))),
+}))
+"""
+
+
+@pytest.mark.parametrize("n_kv_heads,head_dim,spec", [
+    pytest.param(4, 64, [None, "data", "model", None, None], id="kv_heads"),
+    pytest.param(2, 64, [None, "data", None, None, "model"], id="head_dim"),
+    pytest.param(2, 30, [None, "data", None, "model", None], id="length"),
+])
+def test_sharded_decode_matches_one_device(n_kv_heads, head_dim, spec):
+    """The one decode path under a mesh: with the cache sharded by each of
+    ``cache_spec``'s fallbacks (kv heads, head_dim, length) over four model
+    shards, decode gives the tokens and the final cache it gives on one
+    device."""
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    env = dict(os.environ, PYTHONPATH=str(repo / "src"), JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    r = subprocess.run([sys.executable, "-c", _DECODE_ON_A_MESH, str(n_kv_heads),
+                        str(head_dim)], capture_output=True, text=True, timeout=300,
+                       env=env, cwd=repo)
+    assert r.returncode == 0, r.stderr[-4000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert out["spec"] == spec
+    assert out["tokens_agree"]
+    assert out["max_diff"] < 1e-4

@@ -1,9 +1,11 @@
 """Compile the main path's device programs at real widths for a TPU v5e
 that is described, not attached: the three Pallas kernels compiled (not
-interpreted) and qwen3-0.6b's serving step at its published widths. What
-the chip's compiler would refuse (unaligned tiles, too much fast memory, a
-program that does not fit the device) fails here, at no chip time. Nothing
-runs, so this says nothing about results or times.
+interpreted), the serving steps at published widths on one chip, and the
+serving and training steps sharded by ``launch/shardings.py``'s rules over
+the four chips of a v5e:2x2. What the chip's compiler would refuse
+(unaligned tiles, too much fast memory, a program that does not fit the
+device) fails here, at no chip time. Nothing runs, so this says nothing
+about results or times.
 
 The topology is described only inside the module fixture: one process at a
 time may load the TPU compiler's library, and it keeps it until it exits.
@@ -14,13 +16,16 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
 from repro.configs.registry import get_config
+from repro.distributed.sharding import use_mesh
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.matmul_probe import matmul
+from repro.launch.shardings import make_rules, shard_inputs, shard_params_like
 from repro.models.model import build_model
 
 V5E_HBM_BYTES = 16 * 2**30
@@ -28,7 +33,8 @@ QWEN = get_config("qwen3-0.6b")
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
+    """The four devices of a described v5e:2x2."""
     from jax.experimental import compilation_cache, topologies
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -42,8 +48,13 @@ def one_chip():
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", was_on)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 def _spec(shape, dtype, sharding):
@@ -152,6 +163,70 @@ def test_qwen3_serving_step_fits_one_chip(one_chip, step):
     weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
     assert weights > 1.1e9  # bf16 published widths, not the smoke variant
     assert used < V5E_HBM_BYTES
+
+
+# (batch, data × model): one sequence over four model shards, and a batch
+# of four split over two data shards, whose decode writes its rows by the
+# batched scatter
+LAYOUTS = {"b1_model4": (1, (1, 4)), "b4_data2_model2": (4, (2, 2))}
+
+
+def _sharded(cfg, devices, batch, mesh_shape, seq, cache_len=None):
+    """``cfg``'s params, and a batch of ``seq`` tokens with its cache of
+    ``cache_len`` slots, sharded by ``make_rules`` over a data × model mesh
+    of ``devices``; and the mesh."""
+    mesh = Mesh(np.array(devices).reshape(mesh_shape), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    model = build_model(cfg)
+    params = shard_params_like(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                               cfg, mesh)
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+    cache = (jax.eval_shape(lambda: model.init_cache(batch, cache_len))
+             if cache_len else None)
+    inputs, cache = shard_inputs(cfg, mesh, {"batch": {"tokens": tokens}, "cache": cache})
+    return model, params, inputs["tokens"], cache, mesh
+
+
+def _fits_a_chip(compiled, params) -> None:
+    """Each chip holds a share of the weights, and its arguments and temps
+    fit its memory."""
+    mem = compiled.memory_analysis()
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert mem.argument_size_in_bytes < weights
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+@pytest.mark.parametrize("step", ["prefill_jit", "decode_tokens"])
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "phi3-mini-3.8b"])
+def test_serving_step_compiles_sharded(v5e_2x2, arch, step, layout):
+    """A 1,024-token prefill, or 16 decode steps over 2,048 cache slots, at
+    published widths, with params and cache sharded by ``make_rules`` over
+    the four chips: each compiles and fits a chip."""
+    cfg = get_config(arch)
+    batch, mesh_shape = LAYOUTS[layout]
+    seq = 1024 if step == "prefill_jit" else 1
+    model, params, tokens, cache, mesh = _sharded(cfg, v5e_2x2, batch, mesh_shape,
+                                                  seq, cache_len=2048)
+    with use_mesh(mesh, make_rules(cfg, mesh)):
+        if step == "prefill_jit":
+            lowered = model.prefill_jit.lower(params, {"tokens": tokens}, cache)
+        else:
+            lowered = model.decode_tokens.lower(params, cache, tokens, n_steps=16)
+        compiled = lowered.compile()
+    _fits_a_chip(compiled, params)
+    if step == "decode_tokens" and batch > 1:
+        assert " scatter(" in compiled.as_text()  # the batched row write
+
+
+def test_qwen3_training_step_compiles_sharded(v5e_2x2):
+    """The gradient of qwen3-0.6b's loss over 4 × 1,024 tokens, sharded by
+    ``make_rules`` over data 2 × model 2: it compiles and fits a chip."""
+    model, params, tokens, _, mesh = _sharded(QWEN, v5e_2x2, 4, (2, 2), 1024)
+    grad = jax.jit(jax.grad(lambda p, b: model.loss(p, b)[0]))
+    with use_mesh(mesh, make_rules(QWEN, mesh)):
+        compiled = grad.lower(params, {"tokens": tokens, "labels": tokens}).compile()
+    _fits_a_chip(compiled, params)
 
 
 COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
