@@ -95,17 +95,25 @@ def decode_attention(
     v_cache: jax.Array,
     lengths: jax.Array,
     *,
+    layer: jax.Array | None = None,
     sm_scale: float | None = None,
-    block_k: int = 256,
     use_pallas: bool = True,
 ) -> jax.Array:
+    """q: (B, H, 1, hd); k/v: (B, K, S, hd), or with ``layer`` the stacked
+    (L, B, K, S, hd) whose layer ``layer`` is attended over. The kernel
+    reads the layer where it lies, in blocks from
+    ``decode_attention.decode_block`` of the shapes; the oracle slices it."""
     if not use_pallas:
+        if layer is not None:
+            k_cache, v_cache = (jax.lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
+                                for c in (k_cache, v_cache))
         return _ref.decode_attention_ref(q, k_cache, v_cache, lengths, sm_scale=sm_scale)
     from .decode_attention import decode_attention as _decode_attention
 
-    # a cache length that block_k does not divide raises in the kernel
+    if layer is None:  # one layer's cache: a stack of one
+        k_cache, v_cache, layer = k_cache[None], v_cache[None], jnp.int32(0)
     return _decode_attention(
-        q, k_cache, v_cache, lengths, sm_scale=sm_scale, block_k=block_k,
+        q, k_cache, v_cache, lengths, layer, sm_scale=sm_scale,
         interpret=runs_interpreted(q),
     )
 

@@ -1,20 +1,26 @@
 """GQA attention: prefill (full / sliding-window / causal) and single-token
 decode against a KV cache.
 
+The Pallas kernels serve where they compile for a TPU and no mesh of
+several devices would have to partition them (``_serves_kernel``).
+Everywhere else the jnp reference serves: on the CPU, where a kernel would
+run interpreted, and in a sharded prefill or decode, which a pallas_call
+cannot be partitioned into.
+
 The transformer's serving prefill (``flash=True``) runs a full causal
-layer (no window, no cross attention) through the Pallas flash kernel
-(``kernels/flash_attention.py``) where it compiles for a TPU and no mesh of
-several devices partitions it. Everywhere else it takes the jnp reference:
-on the CPU, where the kernel would run interpreted; in a sharded prefill,
-which a pallas_call cannot be partitioned into; and in the training
-forward, which is differentiated and the kernel has no reverse-mode rule.
+layer (no window, no cross attention) through the flash kernel
+(``kernels/flash_attention.py``). The training forward keeps the
+reference: it is differentiated, and the kernel has no reverse-mode rule.
 Window layers take the banded path once the prompt outgrows the window,
 cross attention and non-causal calls the reference.
 
-Decode has one path on every backend and mesh: the new row is written in
-place (one ``dynamic_update_slice`` for one sequence, one clamped scatter
-for a batch, a per-layer ``vmap`` where the cache is not a stack) and the
-step attends by the jnp reference, ``kref.decode_attention_ref``.
+Decode writes the new row in place (one ``dynamic_update_slice`` for one
+sequence, one clamped scatter for a batch, a per-layer ``vmap`` where the
+cache is not a stack). A step over a stacked cache (``layer`` given)
+attends by the decode kernel (``kernels/decode_attention.py``), which reads
+the layer where it lies in the stack and only its valid prefix; a per-layer
+cache, and every step where the kernel does not serve, by the jnp
+reference (``ops.decode_attention(..., use_pallas=False)``).
 """
 from __future__ import annotations
 
@@ -28,14 +34,6 @@ from repro.distributed.sharding import current_mesh, shard
 from repro.kernels import ops
 from repro.kernels import ref as kref
 from .layers import normal_init, rms_norm, rope
-
-
-def _layer_of(cache: jax.Array, layer: Optional[jax.Array]) -> jax.Array:
-    """Layer ``layer`` of a stacked (L, B, K, S, hd) cache; ``cache`` itself
-    where ``layer`` is None."""
-    if layer is None:
-        return cache
-    return jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
 
 
 def _write_cache_row(cache: jax.Array, new: jax.Array, slot: jax.Array,
@@ -119,7 +117,7 @@ def prefill_attention(
     """Returns (out (B,S,d), (k_cache, v_cache) in (B,K,S,hd) layout).
     ``yarn`` (a ``RopeConfig``) switches RoPE to YaRN (``layers.rope``).
     ``flash``: a serving caller's full causal layer may take the flash
-    kernel (``_serves_flash``); a forward that is differentiated keeps the
+    kernel (``_serves_kernel``); a forward that is differentiated keeps the
     reference."""
     if cross_kv is None:
         q, k, v = _project_qkv(p, x, positions, rope_theta, eps, use_rope, yarn)
@@ -135,7 +133,7 @@ def prefill_attention(
     kh = k.transpose(0, 2, 1, 3)
     vh = v.transpose(0, 2, 1, 3)
     bq = _band_block(qh.shape[2], window) if causal and cross_kv is None else None
-    if flash and causal and window is None and cross_kv is None and _serves_flash(qh):
+    if flash and causal and window is None and cross_kv is None and _serves_kernel(qh):
         out = _flash_prefill(qh, kh, vh)
     elif bq:
         out = _banded_attention(qh, kh, vh, window, bq)
@@ -147,9 +145,9 @@ def prefill_attention(
     return y, (kh, vh)
 
 
-def _serves_flash(q: jax.Array) -> bool:
-    """A full causal prefill runs the flash kernel where it compiles for a
-    TPU and no mesh of several devices would have to partition it."""
+def _serves_kernel(q: jax.Array) -> bool:
+    """A Pallas kernel serves where it compiles for a TPU and no mesh of
+    several devices would have to partition it."""
     mesh = current_mesh()
     return not ops.runs_interpreted(q) and (mesh is None or mesh.size == 1)
 
@@ -228,7 +226,9 @@ def decode_attention_step(
     With ``layer`` (an int32 scalar), the caches are the whole stack of
     layers: the new row is written into layer ``layer`` in place, that
     layer is attended over, and the stack is returned, so a layer scan can
-    carry it instead of slicing and re-stacking it every step.
+    carry it instead of slicing and re-stacking it every step. Where the
+    kernels serve (``_serves_kernel``) the decode kernel attends over the
+    layer where it lies in the stack, reading only its valid prefix.
     """
     B, _, d = x.shape
     S = k_cache.shape[-2]
@@ -245,8 +245,8 @@ def decode_attention_step(
         valid = jnp.minimum(lengths + 1, S)
     else:
         valid = jnp.minimum(lengths, S)
-    out = kref.decode_attention_ref(
-        qh, _layer_of(k_cache, layer), _layer_of(v_cache, layer), valid)
+    out = ops.decode_attention(qh, k_cache, v_cache, valid, layer=layer,
+                               use_pallas=layer is not None and _serves_kernel(qh))
     out = out.transpose(0, 2, 1, 3)
     y = jnp.einsum("bshk,hkd->bsd", out, p.wo)
     return shard(y, "batch", None, None), k_cache, v_cache

@@ -256,8 +256,10 @@ class ModelServingBackend:
     def cache_len(self, prompt_len: int, max_new_tokens: int) -> int:
         """KV-cache length the jitted path allocates for a request shape.
         It is bucketed, so decode_tokens executables are shared across
-        prompt lengths that land in the same bucket (decode attention masks
-        by ``lengths``, so the padded tail is never read)."""
+        prompt lengths that land in the same bucket. On the TPU the decode
+        kernel reads only the valid prefix, so the padded tail is never
+        read; the jnp reference elsewhere reads it and masks it by
+        ``lengths``."""
         steps = _bucket(max_new_tokens, base=self.decode_bucket)
         return _bucket(prompt_len + steps, base=self.decode_bucket)
 

@@ -35,7 +35,7 @@ def flash_calls(monkeypatch):
         calls.append(q.shape)
         return run(q, k, v)
 
-    monkeypatch.setattr(attention, "_serves_flash", lambda q: True)
+    monkeypatch.setattr(attention, "_serves_kernel", lambda q: True)
     monkeypatch.setattr(attention, "_flash_prefill", recorded)
     return calls
 
@@ -43,7 +43,7 @@ def flash_calls(monkeypatch):
 def test_cpu_prefill_takes_the_reference():
     """Off a TPU the kernel would run interpreted: the reference serves."""
     q = jnp.ones((1, 4, 8, 32))
-    assert not attention._serves_flash(q)
+    assert not attention._serves_kernel(q)
 
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)])
@@ -55,7 +55,7 @@ def test_flash_prefill_pads_a_ragged_prompt(flash_calls, monkeypatch, S, dtype, 
     kw = dict(rope_theta=10_000.0, eps=1e-6, causal=True, flash=True)
     got, (k, v) = attention.prefill_attention(p, x, pos, **kw)
     assert flash_calls == [(1, 4, S, 32)]
-    monkeypatch.setattr(attention, "_serves_flash", lambda q: False)
+    monkeypatch.setattr(attention, "_serves_kernel", lambda q: False)
     want, (k_ref, v_ref) = attention.prefill_attention(p, x, pos, **kw)
     assert len(flash_calls) == 1
     assert got.shape == want.shape == (1, S, 64)
@@ -103,7 +103,7 @@ def test_model_prefill_with_the_flash_kernel(flash_calls, monkeypatch):
 
     logits, cache = run()
     assert len(flash_calls) == 1
-    monkeypatch.setattr(attention, "_serves_flash", lambda q: False)
+    monkeypatch.setattr(attention, "_serves_kernel", lambda q: False)
     want, want_cache = run()
     assert len(flash_calls) == 1
     np.testing.assert_allclose(np.asarray(logits, np.float32), np.asarray(want, np.float32),

@@ -128,14 +128,18 @@ def test_qwen3_training_step_compiles_without_the_kernel(one_chip, monkeypatch):
     assert "prefill_flash" not in text
 
 
-def test_decode_attention_compiles_at_qwen3_heads(one_chip):
-    batch, cache_len, hd = 4, 2048, QWEN.head_dim
+@pytest.mark.parametrize("cache_len", [2048, 1000])
+def test_decode_attention_compiles_at_qwen3_heads(one_chip, cache_len):
+    """A batch of four over a layer of qwen3-0.6b's stacked cache, of a
+    length its block divides and of one that ends in a partial block."""
+    batch, hd = 4, QWEN.head_dim
     q = _spec((batch, QWEN.n_heads, 1, hd), jnp.bfloat16, one_chip)
-    kv = _spec((batch, QWEN.n_kv_heads, cache_len, hd), jnp.bfloat16, one_chip)
+    kv = _spec((QWEN.n_layers, batch, QWEN.n_kv_heads, cache_len, hd), jnp.bfloat16, one_chip)
     lengths = _spec((batch,), jnp.int32, one_chip)
-    text = decode_attention.lower(q, kv, kv, lengths,
+    layer = _spec((), jnp.int32, one_chip)
+    text = decode_attention.lower(q, kv, kv, lengths, layer,
                                   interpret=False).compile().as_text()
-    assert "tpu_custom_call" in text
+    assert re.search(r"%decode_attention\S* = .*custom_call_target=\"tpu_custom_call\"", text)
 
 
 @pytest.mark.parametrize("step", ["prefill_jit", "decode_tokens"])
@@ -248,9 +252,13 @@ def _computations(text: str) -> dict[str, list[str]]:
     return comps
 
 
-def _decode_text(cfg, one_chip, cache_len):
+def _decode_text(cfg, one_chip, cache_len, kernel=True):
     """The decode executable of ``cfg`` for 16 steps, as the chip's compiler
-    builds it, and its cache's shapes."""
+    builds it, and its cache's shapes: with the kernels compiled as on one
+    chip, or (``kernel=False``) with the jnp reference that serves a mesh of
+    several devices."""
+    from repro.kernels import ops
+
     model = build_model(cfg)
 
     def on_chip(tree):
@@ -259,18 +267,28 @@ def _decode_text(cfg, one_chip, cache_len):
     params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     cache = on_chip(jax.eval_shape(lambda: model.init_cache(1, cache_len)))
     tok = _spec((1, 1), jnp.int32, one_chip)
-    return model.decode_tokens.lower(params, cache, tok, n_steps=16).compile().as_text(), cache
+    with pytest.MonkeyPatch.context() as m:
+        if kernel:
+            m.setattr(ops, "runs_interpreted", lambda x: False)
+        lowered = model.decode_tokens.lower(params, cache, tok, n_steps=16)
+    return lowered.compile().as_text(), cache
 
 
-def test_decode_loop_copies_no_whole_cache(one_chip):
+def _shape(a) -> str:
+    return "bf16[%s]" % ",".join(map(str, a.shape))
+
+
+@pytest.mark.parametrize("path", ["kernel", "reference"])
+def test_decode_loop_copies_no_whole_cache(one_chip, path):
     """qwen3-0.6b's decode at 2,048 cache slots, as the chip's compiler
     builds it: the layer scan carries the stacked cache, so no while body
-    (nor anything it calls) copies the whole stacked cache. The copies at
-    the executable's entry and exit run once per request."""
-    text, cache = _decode_text(QWEN, one_chip, 2048)
-    stacked = "bf16[%s]" % ",".join(map(str, cache["k"]["full"].shape))
+    (nor anything it calls) copies the whole stacked cache; with the kernel
+    none slices a layer out of it either. The copies at the executable's
+    entry and exit run once per request."""
+    text, cache = _decode_text(QWEN, one_chip, 2048, kernel=path == "kernel")
+    stacked = _shape(cache["k"]["full"])
     assert stacked == "bf16[28,1,8,2048,128]"
-    _assert_no_loop_copies(text, [stacked])
+    _assert_no_loop_copies(text, [stacked], parts=path == "kernel")
 
 
 def _mellum2_one_chip():
@@ -280,21 +298,31 @@ def _mellum2_one_chip():
     return dataclasses.replace(full, moe=dataclasses.replace(full.moe, n_held=16))
 
 
-def test_mellum2_decode_loop_copies_no_cache_stack(one_chip):
+@pytest.mark.parametrize("path", ["kernel", "reference"])
+def test_mellum2_decode_loop_copies_no_cache_stack(one_chip, path):
     """mellum2-12b-a2.5b's decode with one chip's experts, at 4,096 cache
     slots: the period scan carries both cache stacks, the ring of the 21
     window layers and the 7 full layers' stack, and no loop body copies
-    either whole."""
-    text, cache = _decode_text(_mellum2_one_chip(), one_chip, 4096)
-    stacks = ["bf16[%s]" % ",".join(map(str, cache["k"][kind].shape)) for kind in ("window", "full")]
+    either whole; with the kernel none copies or slices a part of either,
+    into another memory space neither."""
+    text, cache = _decode_text(_mellum2_one_chip(), one_chip, 4096, kernel=path == "kernel")
+    stacks = [_shape(cache["k"][kind]) for kind in ("window", "full")]
     assert stacks == ["bf16[21,1,4,1024,128]", "bf16[7,1,4,4096,128]"]
-    _assert_no_loop_copies(text, stacks)
+    _assert_no_loop_copies(text, stacks, parts=path == "kernel")
 
 
-def _assert_no_loop_copies(text: str, stacked: list[str]) -> None:
+def _assert_no_loop_copies(text: str, stacked: list[str], parts: bool = True) -> None:
+    """No loop body, nor anything it calls, copies a stack of ``stacked``
+    whole; with ``parts``, none copies or slices a part of one either: not a
+    layer, not into another memory space. (The reference's attention reads
+    a layer by a slice fused into its scores: no copy, but a slice.)"""
     comps = _computations(text)
     todo = [m.group(1) for line in text.splitlines() if (m := WHILE_BODY.search(line))]
     assert len(todo) >= 2, "the step loop and the layer loop"
+    moves = re.compile(r"(?<![\w-])(?:copy|copy-start|slice|slice-start|dynamic-slice)\(")
+    # a stack's dims after its layer axis, under any count of layers
+    part = re.compile("|".join(r"bf16\[\d+," + re.escape(s.split(",", 1)[1]) for s in stacked))
+    whole = re.compile("|".join(re.escape(s) + r"\{[^}]*\} copy(?:-start)?\(" for s in stacked))
     seen = set()
     while todo:
         name = todo.pop()
@@ -303,9 +331,39 @@ def _assert_no_loop_copies(text: str, stacked: list[str]) -> None:
         seen.add(name)
         for line in comps[name]:
             todo += CALLED.findall(line)
-            for shape in stacked:
-                assert not re.search(re.escape(shape) + r"\{[^}]*\} copy(?:-start)?\(", line), (
-                    name, line[:200])
+            assert not whole.search(line), (name, line[:200])
+            if parts:
+                assert not (moves.search(line) and part.search(line)), (name, line[:200])
+
+
+# (config, cache slots): each served configuration at a long cache of its mix
+DECODE_KERNEL = {
+    "qwen3-0.6b": (lambda: QWEN, 8192),
+    "phi3-mini-3.8b": (lambda: get_config("phi3-mini-3.8b"), 2048),
+    "mellum2-12b-a2.5b": (lambda: _mellum2_one_chip(), 4096),
+}
+
+
+@pytest.mark.parametrize("arch", list(DECODE_KERNEL))
+def test_decode_runs_the_kernel_in_the_layer_loop(one_chip, arch):
+    """The decode executable of each served configuration as the chip runs
+    it: each attention layer of a period calls the decode kernel in the
+    layer loop's body, with its kind's whole stack as K and V, and no loop
+    body copies or slices a stack or a layer of it."""
+    make, cache_len = DECODE_KERNEL[arch]
+    cfg = make()
+    text, cache = _decode_text(cfg, one_chip, cache_len)
+    comps = _computations(text)
+    bodies = {m.group(1) for line in text.splitlines() if (m := WHILE_BODY.search(line))}
+    calls = [line for name in bodies for line in comps[name]
+             if re.search(r"%decode_attention\S* = .*custom-call\(", line)]
+    stacks = [_shape(cache["k"][kind]) for kind in cfg.period]
+    assert len(calls) == len(stacks)
+    for line in calls:
+        # operands: layer, lengths, q, then the K and V stacks
+        operands = re.findall(r"\w+\[[\d,]*\]", line.split("operand_layout_constraints=")[1])
+        assert operands[3] == operands[4] in stacks
+    _assert_no_loop_copies(text, sorted(set(stacks)))
 
 
 def test_mellum2_decode_reads_each_expert_where_it_lies(one_chip):
